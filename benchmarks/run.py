@@ -40,6 +40,9 @@ def main() -> None:
     t0 = time.time()
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     from repro.core.offline import characterize
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from benchmarks import (characterization, energy, kernels_bench,
                             operating_modes, overhead, roofline,

@@ -290,43 +290,89 @@ def bench_streaming(cd=None, n_jobs=1500, pools=(2, 5, 5),
     return out
 
 
+def backlog_churn(cd, make_policy, J, pools, iters=40, churn=64,
+                  tick=1.0, utilization=0.8):
+    """The standing-backlog churn loop behind ``bench_sched_overhead``.
+
+    Queues ``J`` jobs of a seeded MMPP trace on ``synth_fleet(*pools)``,
+    runs one warm-up ``schedule`` call, then for each of ``iters`` ticks
+    frees ``churn`` random workers, times one ``schedule`` call, applies
+    its assignments (placed jobs leave the queue, their workers go busy)
+    and injects ``churn`` fresh arrivals.  Every input is seeded, so two
+    policies see the identical stream until their assignments differ.
+    Returns ``(assignments, tick_seconds, policy)``: per tick the list of
+    ``(job id, worker)`` pairs, the host wall-clock of each call, and
+    the policy ``make_policy()`` built."""
+    from repro.core.job import exec_time
+    from repro.core.workers import synth_fleet
+    from repro.core.workload import scenario
+
+    fleet = synth_fleet(*pools)
+    W = len(fleet)
+    jobs = scenario(cd, "mmpp", n_jobs=J + iters * churn, fleet=fleet,
+                    utilization=utilization, seed=0)
+    queue = list(jobs[:J])
+    reservoir = jobs[J:]
+    now = queue[-1].arrival
+    pol = make_policy()
+    cl = Simulator(cd, pol, fleet=fleet, seed=0).cluster
+    rng = np.random.default_rng(0)
+    names = cl.arrays.names
+    pol.schedule(now, queue, cl)        # warm caches / tracing
+    ticks, log = [], []
+    for i in range(iters):
+        now += tick
+        for wi in rng.choice(W, size=min(churn, W), replace=False):
+            cl.workers[names[wi]].busy_until = now
+        t0 = time.perf_counter()
+        asg = pol.schedule(now, queue, cl)
+        ticks.append(time.perf_counter() - t0)
+        log.append([(a.job.id, a.worker) for a in asg])
+        placed = set()
+        for a in asg:
+            cl.workers[a.worker].busy_until = (
+                now + exec_time(a.entry, a.job.queries))
+            placed.add(a.job.id)
+        queue = [j for j in queue if j.id not in placed]
+        fresh = reservoir[i * churn:(i + 1) * churn]
+        for j in fresh:
+            j.arrival = now
+        queue.extend(fresh)
+    return log, ticks, pol
+
+
 def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
                                          (10_000, (8, 28, 28))),
                          iters=40, churn=64, tick=1.0,
                          pallas_max_j=2_000, utilization=0.8,
-                         only=None, interpret=True, strict_parity=False,
+                         only=None, interpret=None, strict_parity=False,
                          emit=print):
     """Per-tick scheduler decision time under a standing MMPP backlog.
 
-    A synthetic tick loop keeps the queue depth at ~J while churning it
-    exactly like the simulator does: each tick frees ``churn`` workers,
-    times one ``SynergAI.schedule`` call, applies the assignments
-    (placed jobs leave the queue, their workers go busy) and injects
-    ``churn`` fresh arrivals.  That makes the *incremental* cost visible:
-    the cached variant re-scores only the churn, the uncached variant
-    rebuilds the full [J, W] matrix every tick, and the device-resident
-    ``pallas-resident`` variant ships only the churn's rows host->device
-    and runs the whole decision as one fused dispatch.  Pallas variants
-    run in interpret mode on CPU by default (the kernel emulated
+    ``backlog_churn`` keeps the queue depth at ~J while churning it
+    exactly like the simulator does.  That makes the *incremental* cost
+    visible: the cached variant re-scores only the churn, the uncached
+    variant rebuilds the full [J, W] matrix every tick, and the
+    device-resident ``pallas-resident`` variant ships only the churn's
+    rows host->device and runs the whole decision as one fused dispatch.
+    Pallas variants run compiled on a TPU backend and interpreted
+    elsewhere (``interpret=None``; on CPU the kernel is emulated
     op-by-op — wall-clock is not the point there), capped at
-    ``pallas_max_j``; ``interpret=False`` runs the compiled backend and
-    suffixes the variant names ``-compiled`` so accelerator numbers land
-    under their own regression keys (parity-gated, ratio-tracked —
-    never floored until real accelerator baselines are committed).
+    ``pallas_max_j``; compiled variants carry a ``-compiled`` suffix so
+    their numbers land under their own regression keys.  The times are
+    host wall-clock.
 
     Every Pallas variant's per-tick assignments are compared against the
     cached numpy variant's on the identical churn stream and recorded as
     ``assignments_match_cached``; ``strict_parity=True`` raises on any
     mismatch (the CI smoke contract).  ``only`` restricts the run to
     ``("cached", only)`` for the tier-1 smoke leg."""
-    import numpy as np
-
-    from repro.core.job import exec_time
     from repro.core.pallas_scoring import make_pallas_score_fn
     from repro.core.workers import synth_fleet
-    from repro.core.workload import scenario
+    from repro.kernels.ops import resolve_interpret
 
     cd = cd or characterize()
+    interpret = resolve_interpret(interpret)
     suffix = "" if interpret else "-compiled"
     variants = [
         ("uncached", lambda: SynergAI(incremental=False)),
@@ -344,47 +390,17 @@ def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
         variants = [v for v in variants if v[0] in keep]
     results = []
     for J, pools in sizes:
-        fleet = synth_fleet(*pools)
-        W = len(fleet)
+        W = len(synth_fleet(*pools))
         base = {}
         cached_log = None
         for name, mk in variants:
             if name.startswith("pallas") and J > pallas_max_j:
                 continue
-            # fresh identical workload per variant (jobs are mutated by
-            # the churn loop below)
-            jobs = scenario(cd, "mmpp", n_jobs=J + iters * churn,
-                            fleet=fleet, utilization=utilization, seed=0)
-            queue = list(jobs[:J])
-            reservoir = jobs[J:]
-            now = queue[-1].arrival
-            pol = mk()
-            sim = Simulator(cd, pol, fleet=fleet, seed=0)
-            cl = sim.cluster
-            rng = np.random.default_rng(0)
-            names = cl.arrays.names
-            pol.schedule(now, queue, cl)        # warm caches / tracing
-            ticks, placed_total, asg_log = [], 0, []
-            for i in range(iters):
-                now += tick
-                for wi in rng.choice(W, size=min(churn, W),
-                                     replace=False):
-                    cl.workers[names[wi]].busy_until = now
-                t0 = time.perf_counter()
-                asg = pol.schedule(now, queue, cl)
-                ticks.append(time.perf_counter() - t0)
-                asg_log.append([(a.job.id, a.worker) for a in asg])
-                placed = set()
-                for a in asg:
-                    cl.workers[a.worker].busy_until = (
-                        now + exec_time(a.entry, a.job.queries))
-                    placed.add(a.job.id)
-                placed_total += len(placed)
-                queue = [j for j in queue if j.id not in placed]
-                fresh = reservoir[i * churn:(i + 1) * churn]
-                for j in fresh:
-                    j.arrival = now
-                queue.extend(fresh)
+            asg_log, ticks, pol = backlog_churn(
+                cd, mk, J, pools, iters=iters, churn=churn, tick=tick,
+                utilization=utilization)
+            placed_total = sum(len({jid for jid, _ in a})
+                               for a in asg_log)
             mean_ms = 1e3 * float(np.mean(ticks))
             p50_ms = 1e3 * float(np.median(ticks))
             rec = {"variant": name, "J": J, "W": W, "serving": "job",
@@ -1001,12 +1017,12 @@ def main(argv=None):
                         "assignment divergence")
     p.add_argument("--sched-backend", choices=("auto", "interpret",
                                                "compiled"),
-                   default="interpret",
+                   default="auto",
                    help="Pallas execution backend for the sched bench: "
-                        "interpret (CPU-emulated, the parity reference), "
-                        "compiled (lowered kernels; variants recorded "
-                        "under '-compiled' keys), auto (compiled on "
-                        "TPU, interpret elsewhere)")
+                        "auto (compiled on TPU, interpret elsewhere), "
+                        "interpret (CPU-emulated), compiled (lowered "
+                        "kernels; variants recorded under '-compiled' "
+                        "keys)")
     p.add_argument("--sched-json", metavar="PATH", default=None,
                    help="write the bench_sched_overhead + bench_regions "
                         "results as JSON (the BENCH_SCHED.json schema; "
@@ -1040,6 +1056,8 @@ def main(argv=None):
                    help="dump the serving/streaming bench summaries as "
                         "JSON (CI artifact)")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cd = characterize()
     blob = {}
     if not args.skip_paper:
@@ -1048,11 +1066,8 @@ def main(argv=None):
     if not args.skip_scoring:
         print("# scoring: numpy vs Pallas kernel")
         bench_scoring(cd)
-    if args.sched_backend == "auto":
-        import jax
-        interpret = jax.default_backend() != "tpu"
-    else:
-        interpret = args.sched_backend == "interpret"
+    interpret = (None if args.sched_backend == "auto"
+                 else args.sched_backend == "interpret")
     sched = None
     if args.sched_smoke:
         print(f"# scheduler overhead smoke: cached vs {args.sched_smoke}"

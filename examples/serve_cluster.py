@@ -2,8 +2,11 @@
 requests flow through the SynergAI scheduler onto engine replicas, and the
 selected engine actually executes generation with a real model + KV cache.
 
-The model is a reduced-config arch (CPU-friendly); on a TPU fleet the same
-code path runs the full configs under the production mesh.
+It runs the reduced configs (2 layers, d_model 64, float32: the
+``--reduced`` path of ``repro.launch.serve``) so it finishes on a CPU in
+seconds; the scheduler still places each request against the published
+engines' profiles.  The same engine at qwen3-4b's published widths runs on
+a chip with ``python -m repro.launch.serve --arch qwen3-4b``.
 
     PYTHONPATH=src python examples/serve_cluster.py
 """
@@ -11,23 +14,15 @@ code path runs the full configs under the production mesh.
 import time
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs.base import reduced
-from repro.configs.registry import get_config
 from repro.core.estimator import candidate_order, estimate_matrix
 from repro.core.offline import characterize
-from repro.models.registry import build_model
-from repro.serving.engine import InferenceEngine
+from repro.launch.serve import build_engine
 
 # --- bring up two real engine replicas (reduced configs on CPU) -----------
 ARCHS = {"qwen3-4b/bf16": "qwen3-4b", "rwkv6-1.6b/bf16": "rwkv6-1.6b"}
-replicas = {}
-for engine_name, arch in ARCHS.items():
-    cfg = reduced(get_config(arch))
-    model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    replicas[engine_name] = InferenceEngine(model, params, max_len=64)
+replicas = {engine_name: build_engine(arch, reduced=True, max_len=64)
+            for engine_name, arch in ARCHS.items()}
 print(f"brought up {len(replicas)} engine replicas")
 
 # --- offline phase: the scheduler's view of the fleet ----------------------

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.core.estimator import profile_gen
 from repro.core.scorecache import ScoreCache
+from repro.kernels.ops import resolve_interpret
 
 _COL_BLOCK = 128      # worker-axis padding block (f32 TPU lane width)
 _ROW_BLOCK = 256      # slot-pool row padding block (matches _GROW)
@@ -92,9 +93,7 @@ class DeviceScoreCache(ScoreCache):
         self._d_Wp = 0
         super().__init__(use_default, profile)
         self.bj = int(bj)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         # transfer accounting (tests assert the O(churn * W) bound)
         self.fail_masks = 0          # fail_gen bumps absorbed by masking
         self.rows_uploaded = 0       # matrix rows shipped host -> device

@@ -3,8 +3,8 @@
 ``make_pallas_score_fn()`` builds the dense ``[J, W]`` qps/preproc
 matrices from the Configuration Dictionary (cached rows shared with the
 numpy estimator via ``score_matrices``), runs
-``repro.kernels.scheduler_score`` — interpret mode on CPU, compiled on
-TPU — and adapts the outputs to ``ScoreResult`` so that
+``repro.kernels.scheduler_score`` — compiled on a TPU backend, interpret
+mode on any other (``interpret=None``, the default) — and adapts the outputs to ``ScoreResult`` so that
 
     SynergAI(score_fn=make_pallas_score_fn())
 
@@ -37,10 +37,12 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.estimator import ScoreResult, score_matrices
+from repro.kernels.ops import resolve_interpret
 
 
-def make_pallas_score_fn(bj: int = 128, interpret: bool = True,
+def make_pallas_score_fn(bj: int = 128, interpret=None,
                          v2: bool = False, device_cache: bool = False):
+    interpret = resolve_interpret(interpret)
     if device_cache:
         return _make_device_marker(bj, interpret)
     if v2:
@@ -70,15 +72,15 @@ def make_pallas_score_fn(bj: int = 128, interpret: bool = True,
     return score_fn
 
 
-def _make_device_marker(bj: int, interpret):
+def _make_device_marker(bj: int, interpret: bool):
     """``make_pallas_score_fn(device_cache=True)`` — the device-resident
     backend.  Unlike the other variants this is a *marker*, not a scoring
     callable: ``SynergAI`` consumes its attributes to build a
     ``repro.core.devicecache.DeviceScoreCache`` (persistent on-device row
     pools) and routes every tick through the fused ``scheduler_tick``
     kernel dispatch, so no host-side score function ever runs.
-    ``interpret=None`` auto-selects (compiled on TPU, interpret
-    elsewhere)."""
+    ``interpret`` arrives resolved; ``SynergAI`` hands it to the cache,
+    where a chip run checks it (``pol.cache.interpret is False``)."""
     def device_score(*_a, **_k):
         raise TypeError(
             "make_pallas_score_fn(device_cache=True) returns a backend "

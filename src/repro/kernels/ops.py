@@ -1,7 +1,9 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the kernels execute (and are
-validated) on CPU; on a real TPU backend the lowered Mosaic kernels run.
+``interpret=None`` (the default everywhere) resolves through
+``resolve_interpret``: the lowered Mosaic kernels on a TPU backend, the
+Pallas interpreter on any other, which is how the tests run them on CPU.
+A caller that must not fall back checks the resolved value.
 """
 
 from __future__ import annotations
@@ -15,41 +17,44 @@ from repro.kernels import (decode_attention as _da, flash_attention as _fa,
                            scheduler_score as _ss)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret=None) -> bool:
+    """``None`` -> interpret everywhere but on a TPU backend."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, bq=128, bk=128,
                     interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                bq=bq, bk=bk, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("bk", "interpret"))
 def decode_attention(q, k, v, k_valid, *, bk=512, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _da.decode_attention(q, k, v, k_valid, bk=bk,
                                 interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("top_k", "bt", "interpret"))
 def moe_routing(x, router_w, top_k, *, bt=128, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _mr.moe_routing(x, router_w, top_k, bt=bt, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv_scan(r, k, v, w, u, *, chunk=64, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _rs.rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("bj", "interpret"))
 def scheduler_score(qps, preproc, queries, t_remaining, *, bj=128,
                     interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _ss.scheduler_score(qps, preproc, queries, t_remaining, bj=bj,
                                interpret=interpret)

@@ -254,9 +254,12 @@ def _tick_kernel(t_ref, pre_ref, dec_ref, rem_ref, pen_ref, bw_ref,
                    keepdims=True)
     eligd = feas & (t_eff <= 1.5 * best)
     cost_ref[...] = jnp.where(doom_row, costd, t_eff)
-    elig_ref[...] = jnp.where(doom_row, eligd, acc).astype(jnp.int8)
-    urg_ref[...] = urg
-    doom_ref[...] = doom_row[:, 0].astype(jnp.int8)
+    # Mosaic selects and stores 32-bit lanes only: no select between
+    # boolean vectors, no 8-bit outputs
+    elig_ref[...] = jnp.where(doom_row, eligd.astype(jnp.int32),
+                              acc.astype(jnp.int32))
+    urg_ref[...] = urg[:, None]
+    doom_ref[...] = doom_row.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -294,24 +297,26 @@ def scheduler_tick(pool_t, pool_pre, pool_dec, pool_ene, slots, t_rem,
     jw = pl.BlockSpec((bj, W), lambda i: (i, 0))
     j1 = pl.BlockSpec((bj, 1), lambda i: (i, 0))
     w1 = pl.BlockSpec((1, W), lambda i: (0, 0))
-    jv = pl.BlockSpec((bj,), lambda i: (i,))
+    # urgency and doom leave as [Jp, 1] columns: rank-1 blocks of bj
+    # rows do not match the chip's tiling
     cost, elig, urg, doom = pl.pallas_call(
         _tick_kernel,
         grid=(Jp // bj,),
         in_specs=[jw, jw, jw, j1, w1, w1, j1, j1, j1, j1, j1, j1],
-        out_specs=[jw, jw, jv, jv],
+        out_specs=[jw, jw, j1, j1],
         out_shape=[
             jax.ShapeDtypeStruct((Jp, W), jnp.float32),
-            jax.ShapeDtypeStruct((Jp, W), jnp.int8),
-            jax.ShapeDtypeStruct((Jp,), jnp.float32),
-            jax.ShapeDtypeStruct((Jp,), jnp.int8),
+            jax.ShapeDtypeStruct((Jp, W), jnp.int32),
+            jax.ShapeDtypeStruct((Jp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Jp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(t0, pre_m, dec_m, col(t_rem, jnp.float32), row(pen),
       row(busy_wait), col(phase, jnp.int32), col(has_ttft, jnp.int32),
       col(has_tpot, jnp.int32), col(ttft_rem, jnp.float32),
       col(tpot_qos, jnp.float32), col(dtok, jnp.float32))
-    elig = elig.astype(bool)
+    elig = elig != 0
+    urg, doom = urg[:, 0], doom[:, 0]
     if use_energy:
         # the weighted energy/carbon term joins the *ranking* cost only;
         # eligible pairs always carry finite energy rows, so no masking
@@ -322,7 +327,7 @@ def scheduler_tick(pool_t, pool_pre, pool_dec, pool_ene, slots, t_rem,
     ranked = jnp.where(elig, cost, jnp.inf)
     # 2D Ordered Job Queue: urgent first, doomed last, padding after
     # everything (stable sort keeps queue order on ties, like np.lexsort)
-    doomkey = jnp.where(jvalid, doom.astype(jnp.int32), 2)
+    doomkey = jnp.where(jvalid, doom, 2)
     urgkey = jnp.where(jvalid, urg, jnp.inf)
     order = jnp.lexsort((urgkey, doomkey))
     # greedy placement: walk jobs in order, each takes the masked argmin

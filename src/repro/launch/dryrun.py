@@ -162,8 +162,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
                 sh.set_active_mesh(None)
             ma = compiled.memory_analysis()
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):     # jax <= 0.4.x: [dict]
-                ca = ca[0] if ca else {}
             hlo = compiled.as_text()
             from repro.launch.hloparse import collective_bytes_loop_aware
             coll, counts = collective_bytes_loop_aware(hlo)

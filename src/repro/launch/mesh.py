@@ -9,22 +9,16 @@ from __future__ import annotations
 import jax
 
 
-def _auto_kwargs(n):
-    # jax >= 0.5 wants explicit axis_types; older releases have neither
-    # jax.sharding.AxisType nor the make_mesh kwarg — omit it there
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _auto(n):
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh for tests/examples (e.g. (1, 2) on CPU)."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_auto_kwargs(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes), **_auto(len(axes)))

@@ -221,11 +221,11 @@ def init_decoder(key, cfg: ModelConfig):
     params = {"embed": common.init_embedding(k_embed, cfg, dtype),
               "groups": []}
     for g, gk in zip(groups, gkeys):
-        lks = jax.random.split(gk, g.n)
-        stacked = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[_init_layer(lks[i], cfg, g.spec, dtype) for i in range(g.n)])
-        params["groups"].append(stacked)
+        # one layer's init vmapped over the group's keys: the program
+        # stays one layer in size however deep the stack is
+        params["groups"].append(jax.vmap(
+            lambda k, _s=g.spec: _init_layer(k, cfg, _s, dtype))(
+                jax.random.split(gk, g.n)))
     return params
 
 
@@ -283,15 +283,8 @@ def decoder_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
 def init_encoder(key, cfg: ModelConfig):
     dtype = common.dtype_of(cfg)
     n = cfg.encdec.n_enc_layers
-    lks = jax.random.split(key, n)
-    spec = LayerSpec("dense")
-
-    def one(k):
-        p = _init_layer(k, cfg, spec, dtype)
-        return p
-
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
-                           *[one(lks[i]) for i in range(n)])
+    stacked = jax.vmap(lambda k: _init_layer(k, cfg, LayerSpec("dense"),
+                                             dtype))(jax.random.split(key, n))
     return {"layers": stacked,
             "final_norm": jnp.zeros((cfg.d_model,), dtype=dtype)}
 

@@ -43,20 +43,30 @@ class InferenceEngine:
         self._decode = jax.jit(model.decode,
                                donate_argnums=(1,) if donate else ())
 
+    def prefill(self, batch: dict):
+        """Run the prompt; returns (last-position logits [B, V], caches
+        laid into ``max_len`` decode buffers)."""
+        B = batch["tokens"].shape[0]
+        logits, caches = self._prefill(self.params, batch)
+        ctx_len = (batch["audio_embeds"].shape[1]
+                   if "audio_embeds" in batch else None)
+        template = self.model.init_cache(B, self.max_len, ctx_len)
+        return logits, pad_cache(caches, template)
+
+    def decode_step(self, caches, token, pos: int):
+        """One token per row at position ``pos``; ``caches`` is donated.
+        Returns (logits [B, V], caches)."""
+        return self._decode(self.params, caches,
+                            {"token": token[:, None], "pos": jnp.int32(pos)})
+
     def generate(self, batch: dict, n_tokens: int, key=None):
         """batch: model input_specs-shaped dict with real arrays.
 
         Returns tokens [B, n_tokens].
         """
-        B = (batch.get("tokens") if "tokens" in batch
-             else batch["token"]).shape[0]
+        B, prompt_len = batch["tokens"].shape
         t0 = time.perf_counter()
-        logits, caches = self._prefill(self.params, batch)
-        prompt_len = batch["tokens"].shape[1]
-        ctx_len = (batch["audio_embeds"].shape[1]
-                   if "audio_embeds" in batch else None)
-        template = self.model.init_cache(B, self.max_len, ctx_len)
-        caches = pad_cache(caches, template)
+        logits, caches = self.prefill(batch)
         jax.block_until_ready(logits)
         self.stats.prefill_s += time.perf_counter() - t0
         self.stats.prefill_tokens += B * prompt_len
@@ -69,9 +79,7 @@ class InferenceEngine:
             outs.append(tok)
             if i == n_tokens - 1:
                 break
-            step = {"token": tok[:, None],
-                    "pos": jnp.int32(prompt_len + i)}
-            logits, caches = self._decode(self.params, caches, step)
+            logits, caches = self.decode_step(caches, tok, prompt_len + i)
             key, sub = jax.random.split(key)
             tok = self.sampler(logits, sub)
         jax.block_until_ready(tok)
