@@ -12,6 +12,7 @@ import dataclasses
 import itertools
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.configdict import ConfigDict
@@ -390,29 +391,35 @@ def estimate_matrix(cd: ConfigDict, jobs: Sequence[Job], workers: List[str],
                     now: float, use_default: bool = False,
                     token: Optional[int] = None,
                     profile: int = 0) -> ScoreResult:
-    """Vectorized Eq. 1-4 over all queued jobs and all workers."""
-    J = len(jobs)
-    if not J:
-        return ScoreResult.empty(workers)
-    qps, pre = score_matrices(cd, jobs, workers, use_default, token,
-                              profile)
-    q = np.fromiter((float(j.queries) for j in jobs), dtype=np.float64,
-                    count=J)
-    with np.errstate(divide="ignore", invalid="ignore"):
-        t_est = np.where(qps > 0, pre + q[:, None] / qps, np.inf)  # Eq. 2
-    t_rem = np.fromiter((j.t_qos - (now - j.arrival) for j in jobs),
-                        dtype=np.float64, count=J)                 # Eq. 1
-    acceptable = t_rem[:, None] >= t_est                           # Eq. 3
-    # Eq. 4: argmin over acceptable workers; fall back to global argmin of
-    # feasible workers when nothing is acceptable (doomed jobs still run).
-    masked = np.where(acceptable, t_est, np.inf)
-    min_est = t_est.min(axis=1)     # inf where nothing is feasible
-    best = np.where(np.isfinite(masked.min(axis=1)), masked.argmin(1),
-                    np.where(np.isfinite(min_est), t_est.argmin(1), -1))
-    urgency = t_rem - min_est       # -> 0 means about to violate
-    doomed = ~acceptable.any(axis=1)
-    return ScoreResult(workers, t_est, t_rem, acceptable,
-                       best.astype(np.int64), urgency, doomed)
+    """Vectorized Eq. 1-4 over all queued jobs and all workers.  Under an
+    active ``jax.profiler`` trace the call is the host span
+    ``placement.estimate`` (its ``jobs`` and ``workers``)."""
+    with jax.profiler.TraceAnnotation("placement.estimate", jobs=len(jobs),
+                                      workers=len(workers)):
+        J = len(jobs)
+        if not J:
+            return ScoreResult.empty(workers)
+        qps, pre = score_matrices(cd, jobs, workers, use_default, token,
+                                  profile)
+        q = np.fromiter((float(j.queries) for j in jobs),
+                        dtype=np.float64, count=J)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            t_est = np.where(qps > 0, pre + q[:, None] / qps,
+                             np.inf)                               # Eq. 2
+        t_rem = np.fromiter((j.t_qos - (now - j.arrival) for j in jobs),
+                            dtype=np.float64, count=J)             # Eq. 1
+        acceptable = t_rem[:, None] >= t_est                       # Eq. 3
+        # Eq. 4: argmin over acceptable workers; fall back to global argmin
+        # of feasible workers when nothing is acceptable (doomed jobs still
+        # run).
+        masked = np.where(acceptable, t_est, np.inf)
+        min_est = t_est.min(axis=1)     # inf where nothing is feasible
+        best = np.where(np.isfinite(masked.min(axis=1)), masked.argmin(1),
+                        np.where(np.isfinite(min_est), t_est.argmin(1), -1))
+        urgency = t_rem - min_est       # -> 0 means about to violate
+        doomed = ~acceptable.any(axis=1)
+        return ScoreResult(workers, t_est, t_rem, acceptable,
+                           best.astype(np.int64), urgency, doomed)
 
 
 # score_fn protocol markers: SynergAI forwards the cluster's interned

@@ -3,12 +3,19 @@
 This is the per-replica execution engine that SynergAI schedules.  One
 ``InferenceEngine`` corresponds to one deployed "inference engine" in the
 paper's terminology: (architecture x serving configuration) on one worker.
+
+Under an active ``jax.profiler`` trace the engine writes host spans on the
+profiler's clock, nested on the calling thread: ``engine.generate`` for a
+whole request (its ``request`` ordinal, ``rows``, ``prompt_len`` and
+``tokens``), and inside it ``engine.prefill``, ``engine.sample`` for each
+token, ``engine.decode_step`` for each step after the first token (its
+sampling nested in it) and ``engine.wait`` for the final sync and the
+stacking of the tokens.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Optional
 
 import jax
@@ -24,8 +31,6 @@ class EngineStats:
     prefill_tokens: int = 0
     decoded_tokens: int = 0
     batches: int = 0
-    prefill_s: float = 0.0
-    decode_s: float = 0.0
 
 
 class InferenceEngine:
@@ -47,11 +52,12 @@ class InferenceEngine:
         """Run the prompt; returns (last-position logits [B, V], caches
         laid into ``max_len`` decode buffers)."""
         B = batch["tokens"].shape[0]
-        logits, caches = self._prefill(self.params, batch)
-        ctx_len = (batch["audio_embeds"].shape[1]
-                   if "audio_embeds" in batch else None)
-        template = self.model.init_cache(B, self.max_len, ctx_len)
-        return logits, pad_cache(caches, template)
+        with jax.profiler.TraceAnnotation("engine.prefill"):
+            logits, caches = self._prefill(self.params, batch)
+            ctx_len = (batch["audio_embeds"].shape[1]
+                       if "audio_embeds" in batch else None)
+            template = self.model.init_cache(B, self.max_len, ctx_len)
+            return logits, pad_cache(caches, template)
 
     def decode_step(self, caches, token, pos: int):
         """One token per row at position ``pos``; ``caches`` is donated.
@@ -65,28 +71,33 @@ class InferenceEngine:
         Returns tokens [B, n_tokens].
         """
         B, prompt_len = batch["tokens"].shape
-        t0 = time.perf_counter()
-        logits, caches = self.prefill(batch)
-        jax.block_until_ready(logits)
-        self.stats.prefill_s += time.perf_counter() - t0
-        self.stats.prefill_tokens += B * prompt_len
-
-        t0 = time.perf_counter()
-        outs = []
-        key = key if key is not None else jax.random.PRNGKey(0)
-        tok = self.sampler(logits, key)
-        for i in range(n_tokens):
-            outs.append(tok)
-            if i == n_tokens - 1:
-                break
-            logits, caches = self.decode_step(caches, tok, prompt_len + i)
-            key, sub = jax.random.split(key)
-            tok = self.sampler(logits, sub)
-        jax.block_until_ready(tok)
-        self.stats.decode_s += time.perf_counter() - t0
-        self.stats.decoded_tokens += B * n_tokens
         self.stats.batches += 1
-        return jnp.stack(outs, axis=1)
+        with jax.profiler.TraceAnnotation(
+                "engine.generate", request=self.stats.batches, rows=B,
+                prompt_len=prompt_len, tokens=n_tokens):
+            logits, caches = self.prefill(batch)
+            self.stats.prefill_tokens += B * prompt_len
+            outs = []
+            key = key if key is not None else jax.random.PRNGKey(0)
+            with jax.profiler.TraceAnnotation("engine.sample", step=0):
+                tok = self.sampler(logits, key)
+            for i in range(n_tokens):
+                outs.append(tok)
+                if i == n_tokens - 1:
+                    break
+                with jax.profiler.TraceAnnotation("engine.decode_step",
+                                                  step=i + 1):
+                    logits, caches = self.decode_step(caches, tok,
+                                                      prompt_len + i)
+                    key, sub = jax.random.split(key)
+                    with jax.profiler.TraceAnnotation("engine.sample",
+                                                      step=i + 1):
+                        tok = self.sampler(logits, sub)
+            with jax.profiler.TraceAnnotation("engine.wait"):
+                jax.block_until_ready(tok)
+                out = jnp.stack(outs, axis=1)
+            self.stats.decoded_tokens += B * n_tokens
+            return out
 
     def cache_footprint(self, B: int) -> int:
         shapes = jax.eval_shape(lambda: self.model.init_cache(B, self.max_len))
