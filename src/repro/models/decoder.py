@@ -5,6 +5,16 @@ specs are stacked (leading ``n`` dim) and executed with ``lax.scan`` so the
 HLO stays one-layer-sized regardless of depth (compile-time critical for the
 512-device dry-run).  Heterogeneous stacks (VLM cross-attn every 5th layer,
 Hymba's 3 global-attention layers) become multiple scan groups.
+
+Decode carries each group's stacked cache ([n, B, ...] leaves) through
+the scan and hands layer ``i`` a ``layers.StackedLayer`` view of each
+leaf.  A sequence buffer (attention k/v, sliding-window rings, MLA
+ckv/krope) takes its one new position at (i, :, pos) in the carried
+stack, and attention reads layer ``i`` of the written stack; a recurrent
+state (rwkv, mamba) replaces its layer whole; a cross-attention cache is
+read and carried on untouched.  The stack the scan ends with is the
+decode step's output, so under the engine's donation the cache is
+written where it lies and never copied.
 """
 
 from __future__ import annotations
@@ -14,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.models import common, layers
@@ -135,6 +146,7 @@ def _layer_forward(p, cfg: ModelConfig, spec: LayerSpec, x, *, mode, cache,
                    pos, ctx, absorb_mla=False):
     cache = cache or {}
     if spec.kind == "rwkv":
+        cache = layers.read_layer(cache)
         h, tm_cache = layers.rwkv_time_mix(
             p, cfg, rms_norm(x, p["ln1"], cfg.norm_eps), mode=mode,
             cache=cache)
@@ -154,7 +166,8 @@ def _layer_forward(p, cfg: ModelConfig, spec: LayerSpec, x, *, mode, cache,
             p["attn"], cfg, xin, mode=mode, cache=cache.get("attn"),
             pos=pos, window=spec.window)
         s, s_cache = layers.mamba_branch(
-            p["mamba"], cfg, xin, mode=mode, cache=cache.get("mamba"))
+            p["mamba"], cfg, xin, mode=mode,
+            cache=layers.read_layer(cache.get("mamba")))
         h = 0.5 * (rms_norm(a, p["norm_attn"], cfg.norm_eps)
                    + rms_norm(s, p["norm_ssm"], cfg.norm_eps))
         x = x + h
@@ -270,10 +283,47 @@ def decoder_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
             x, new_c = jax.lax.scan(
                 lambda xc, lp: body(xc, (lp, None)), x, gparams)
             new_caches.append(new_c)
-        else:  # decode: caches are consumed and re-emitted
-            x, new_c = jax.lax.scan(body, x, (gparams, gcache))
+        else:  # decode: the stacked caches ride in the loop's carry
+            def step(carry, layer_in, _body=body):
+                xc, stacks = carry
+                stacks = _row_major(stacks)
+                lp, i = layer_in
+                views = jax.tree.map(lambda s: layers.StackedLayer(s, i),
+                                     stacks)
+                y, new_c = _body(xc, (lp, views))
+                return (y, jax.tree.map(lambda s, c: _commit(s, c, i),
+                                        stacks, new_c)), None
+
+            (x, new_c), _ = jax.lax.scan(
+                step, (x, gcache), (gparams, jnp.arange(g.n)))
             new_caches.append(new_c)
     return x, new_caches
+
+
+def _row_major(stacks):
+    """Pin the carried stacks to the row-major layout they have as the
+    decode step's arguments and outputs.  Left free, the TPU compiler may
+    give the loop the transposed layout that attention's reads prefer (it
+    does at batch 1) and copy each whole stack into it before the loop
+    and back after.  On a mesh the stacks are left free: GSPMD has no
+    partitioning rule for the constraint and would gather each whole
+    stack to every device."""
+    from repro.distributed.sharding import get_active_mesh
+    if get_active_mesh() is not None:
+        return stacks
+    return jax.tree.map(
+        lambda s: with_layout_constraint(s, Layout(tuple(range(s.ndim)))),
+        stacks)
+
+
+def _commit(stack, new, i):
+    """Layer ``i``'s decode output ``new`` for one stacked cache leaf: a
+    ``StackedLayer`` is the stack itself, written in place at one
+    position or handed back untouched; an array (a recurrent state)
+    replaces the layer whole."""
+    if isinstance(new, layers.StackedLayer):
+        return new.stack
+    return jax.lax.dynamic_update_index_in_dim(stack, new, i, 0)
 
 
 # ----------------------------------------------------------------------------

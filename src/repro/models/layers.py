@@ -6,14 +6,16 @@ Uniform sublayer interface so the generic decoder can scan stacked layers:
     <kind>_layer(params, cfg, x, *, mode, cache, pos, ctx) -> (y, new_cache)
 
 ``mode``  : "train" | "prefill" | "decode"
-``cache`` : per-layer cache pytree (None in train mode)
+``cache`` : per-layer cache pytree (None in train mode); in decode its
+            leaves are ``StackedLayer`` views of the decoder's stacked
+            cache (see ``_cache_write``)
 ``pos``   : scalar int32 — absolute position of the incoming token (decode)
 ``ctx``   : encoder/vision context [B, S_ctx, D] for cross-attention layers
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +28,8 @@ from repro.models.common import (apply_rope, attention, dense_init,
 # When True, decode-cache writes use a one-hot masked update instead of
 # dynamic_update_slice.  DUS at a dynamic index on a sequence-sharded cache
 # makes GSPMD gather the full cache per layer ("involuntary full remat");
-# the one-hot formulation is purely elementwise and stays shard-local.
+# the one-hot formulation is purely elementwise and stays shard-local (the
+# written layer goes back into its stack along the unsharded layer axis).
 # (§Perf optimization — the paper-faithful baseline uses DUS.)
 ONEHOT_CACHE_UPDATE = False
 
@@ -36,15 +39,47 @@ ONEHOT_CACHE_UPDATE = False
 SHARDED_DECODE_ATTN = False
 
 
-def _cache_write(buf, update, idx):
-    """Write ``update`` [B, 1, ...] into ``buf`` [B, S, ...] at ``idx``."""
-    if not ONEHOT_CACHE_UPDATE:
-        return jax.lax.dynamic_update_slice_in_dim(buf, update, idx, axis=1)
-    S = buf.shape[1]
-    onehot = (jnp.arange(S, dtype=jnp.int32) == idx).astype(buf.dtype)
-    shape = (1, S) + (1,) * (buf.ndim - 2)
-    onehot = onehot.reshape(shape)
-    return buf * (1 - onehot) + update.astype(buf.dtype) * onehot
+class StackedLayer(NamedTuple):
+    """Layer ``i`` of a cache leaf stacked over a scan group's layers
+    (``stack`` is [n, B, ...]): what the decoder's decode loop hands a
+    sublayer in place of the layer's own buffer."""
+    stack: jax.Array
+    i: jax.Array
+
+    def read(self):
+        return jax.lax.dynamic_index_in_dim(self.stack, self.i, 0,
+                                            keepdims=False)
+
+
+def read_layer(cache):
+    """``cache`` with each ``StackedLayer`` view replaced by its layer."""
+    return jax.tree.map(StackedLayer.read, cache,
+                        is_leaf=lambda c: isinstance(c, StackedLayer))
+
+
+def _cache_write(buf: StackedLayer, update, idx):
+    """Write ``update`` [B, 1, ...] at sequence position ``idx`` of the
+    layer ``buf`` views.
+
+    The one position goes into the stack the decode loop carries, at
+    (i, :, idx), and attention reads layer ``i`` of the written stack, so
+    no layer of the cache is copied.  Returns (the written view, its
+    [B, S, ...] layer).
+    """
+    if ONEHOT_CACHE_UPDATE:
+        layer = buf.read()
+        S = layer.shape[1]
+        onehot = (jnp.arange(S, dtype=jnp.int32) == idx).astype(layer.dtype)
+        onehot = onehot.reshape((1, S) + (1,) * (layer.ndim - 2))
+        layer = layer * (1 - onehot) + update.astype(layer.dtype) * onehot
+        stack = jax.lax.dynamic_update_index_in_dim(buf.stack, layer, buf.i,
+                                                    0)
+    else:
+        zero = jnp.zeros((), jnp.int32)
+        start = (buf.i, zero, idx) + (zero,) * (update.ndim - 2)
+        stack = jax.lax.dynamic_update_slice(buf.stack, update[None], start)
+    out = StackedLayer(stack, buf.i)
+    return out, out.read()
 
 
 # =============================================================================
@@ -105,14 +140,14 @@ def attn_sublayer(p, cfg: ModelConfig, x, *, mode, cache, pos, window):
         else:
             new_cache = {"k": k, "v": v}
     else:  # decode: write one token, attend over the cache
-        buf = cache["k"].shape[1]
         if window is not None:
             idx = jax.lax.rem(pos, jnp.int32(window))
         else:
             idx = pos
-        ck = _cache_write(cache["k"], k, idx)
-        cv = _cache_write(cache["v"], v, idx)
-        new_cache = {"k": ck, "v": cv}
+        new_k, ck = _cache_write(cache["k"], k, idx)
+        new_v, cv = _cache_write(cache["v"], v, idx)
+        new_cache = {"k": new_k, "v": new_v}
+        buf = ck.shape[1]
         if window is not None:
             # every slot in the ring is within the window once warm; a
             # validity bound covers the cold start.
@@ -152,8 +187,8 @@ def cross_sublayer(p, cfg: ModelConfig, x, *, mode, cache, ctx):
     if cfg.qk_norm:
         q = head_rms_norm(q, p["q_norm"], cfg.norm_eps)
     if mode == "decode" and cache is not None:
-        ck, cv = cache["ck"], cache["cv"]
-        new_cache = cache
+        ck, cv = read_layer(cache["ck"]), read_layer(cache["cv"])
+        new_cache = cache  # read only: handed back as it came
     else:
         ck = jnp.einsum("bsd,dhk->bshk", ctx, p["wk"])
         cv = jnp.einsum("bsd,dhk->bshk", ctx, p["wv"])
@@ -231,9 +266,9 @@ def mla_sublayer(p, cfg: ModelConfig, x, *, mode, cache, pos,
     k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
 
     if mode == "decode":
-        ckv_full = _cache_write(cache["ckv"], ckv, pos)
-        krope_full = _cache_write(cache["krope"], k_rope, pos)
-        new_cache = {"ckv": ckv_full, "krope": krope_full}
+        new_ckv, ckv_full = _cache_write(cache["ckv"], ckv, pos)
+        new_krope, krope_full = _cache_write(cache["krope"], k_rope, pos)
+        new_cache = {"ckv": new_ckv, "krope": new_krope}
         k_valid = pos + 1
         causal = False
     else:

@@ -107,6 +107,34 @@ def test_sliding_window_ring_decode(arch):
     assert rel < 2e-3, f"{arch}: ring cache broke at wrap, rel={rel}"
 
 
+@pytest.mark.parametrize("arch", ["qwen3-4b", "h2o-danube-1.8b", "hymba-1.5b",
+                                  "rwkv6-1.6b", "deepseek-v2-236b"])
+def test_consecutive_decode_steps_match_full_forward(arch):
+    """Several decode steps in a row, the cache donated from one to the
+    next as the engine does, each matching the full forward: a write to
+    the wrong layer or position of the stacked cache shows from the
+    second step on.  Sliding-window configs start at two windows, so
+    their ring wraps under the decode steps."""
+    cfg = reduced(get_config(arch))
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(4)
+    params = model.init_params(key)
+    B, steps = 2, 4
+    S = 2 * cfg.sliding_window if cfg.sliding_window else 12
+    toks = jax.random.randint(key, (B, S + steps), 0, cfg.vocab)
+    _, caches = model.prefill(params, {"tokens": toks[:, :S]})
+    caches = pad_cache(caches, model.init_cache(B, S + steps))
+    decode = jax.jit(model.decode, donate_argnums=(1,))
+    for j in range(steps):
+        logits_full, _ = model.prefill(params, {"tokens": toks[:, :S + j + 1]})
+        logits_step, caches = decode(
+            params, caches,
+            {"token": toks[:, S + j:S + j + 1], "pos": jnp.int32(S + j)})
+        rel = (np.max(np.abs(logits_full - logits_step))
+               / (np.max(np.abs(logits_full)) + 1e-9))
+        assert rel < 2e-3, f"{arch}: decode step {j} diverges, rel={rel}"
+
+
 def test_param_count_sane():
     # analytic parameter counts should be within 35% of actual init sizes
     # (analytic count skips small norm/bias tensors)
