@@ -8,12 +8,29 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                 # experts the router scores
     top_k: int
     n_shared: int = 0
     d_ff_expert: int = 0           # per-expert intermediate size
-    capacity_factor: float = 1.25  # GShard-style dispatch capacity
+    capacity_factor: float = 1.25  # GShard dispatch capacity (mesh path)
     router_dtype: str = "float32"
+    # group-limited routing: the experts fall into n_group equal groups, a
+    # token keeps the topk_group groups with the best expert score and
+    # picks its top_k experts among them (n_group=1: plain top-k)
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True    # renormalise the chosen weights ...
+    routed_scaling_factor: float = 1.0  # ... or else scale them by this
+    # the contiguous range of experts this layer holds (its expert-parallel
+    # share): weights of experts held_start .. held_start + n_held - 1;
+    # n_held=0 holds all of them
+    held_start: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first expert held, number held)."""
+        return self.held_start, self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -23,6 +40,17 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 applies it."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,9 +92,11 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     global_layers: Tuple[int, ...] = ()   # layers that stay global under SWA
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     norm_eps: float = 1e-6
     scale_embeddings: bool = False        # gemma-style sqrt(d_model) embed scale
     moe: Optional[MoEConfig] = None
+    n_dense_layers: int = 0        # leading layers with a d_ff MLP, not experts
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     vision: Optional[VisionConfig] = None
@@ -77,6 +107,7 @@ class ModelConfig:
     remat: bool = True
     attn_chunk: int = 512          # kv-chunk for the XLA flash path
     flash_threshold: int = 2048    # use chunked flash for seq >= threshold
+    prefill_tokens: int = 0        # tokens a prefill runs at once (0: all)
 
     @property
     def sub_quadratic(self) -> bool:
@@ -182,6 +213,11 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         changes["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=2, d_ff_expert=64,
             capacity_factor=8.0)
+        if cfg.moe.n_group > 1:     # groups of 2, keep 2 of 4
+            changes["moe"] = dataclasses.replace(
+                changes["moe"], n_experts=8, n_group=4, topk_group=2)
+    if cfg.n_dense_layers:          # the leading dense layers, then two
+        changes["n_layers"] = cfg.n_dense_layers + 2
     if cfg.mla is not None:
         changes["mla"] = MLAConfig(kv_lora_rank=32, q_lora_rank=48,
                                    qk_nope_head_dim=16, qk_rope_head_dim=8,

@@ -78,8 +78,7 @@ def collective_bytes(hlo_text: str):
     return per_op, counts
 
 
-def build_cell_fn(arch: str, shape_name: str, mesh, absorb_mla=False,
-                  extra_tags=()):
+def build_cell_fn(arch: str, shape_name: str, mesh, extra_tags=()):
     """Returns (fn, example_args, in_shardings, donate) for one cell."""
     cfg = get_config(arch)
     model = build_model(cfg)
@@ -122,19 +121,15 @@ def build_cell_fn(arch: str, shape_name: str, mesh, absorb_mla=False,
     cache_shapes = jax.eval_shape(
         lambda: model.init_cache(shape.global_batch, shape.seq_len))
     c_spec = sh.cache_pspecs(cache_shapes, mesh)
-    if absorb_mla:
-        def step(params, caches, batch):
-            return model.decode(params, caches, batch, absorb_mla=True)
-    else:
-        def step(params, caches, batch):
-            return model.decode(params, caches, batch)
+    def step(params, caches, batch):
+        return model.decode(params, caches, batch)
     args = (params_shapes, cache_shapes, batch_specs)
     in_specs = (p_spec, c_spec, b_spec)
     return step, args, in_specs, (1,)
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
-             absorb_mla=False, tag="") -> dict:
+             tag="") -> dict:
     mesh_name = "multi" if multi_pod else "single"
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
@@ -146,7 +141,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         try:
             mesh = make_production_mesh(multi_pod=multi_pod)
             fn, args, in_specs, donate = build_cell_fn(
-                arch, shape_name, mesh, absorb_mla=absorb_mla)
+                arch, shape_name, mesh)
             sh.set_active_mesh(mesh)
             try:
                 with mesh:
@@ -158,6 +153,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
                     t_lower = time.time() - t0
                     compiled = lowered.compile()
                     t_compile = time.time() - t0 - t_lower
+                    # loop-aware jaxpr FLOP/byte counts of the program as
+                    # compiled on the mesh (cost_analysis counts scan
+                    # bodies once; see launch/flopcount.py)
+                    from repro.launch import flopcount
+                    jx = flopcount.analyze(fn, *args)
             finally:
                 sh.set_active_mesh(None)
             ma = compiled.memory_analysis()
@@ -166,10 +166,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             from repro.launch.hloparse import collective_bytes_loop_aware
             coll, counts = collective_bytes_loop_aware(hlo)
             coll_flat, _ = collective_bytes(hlo)  # unscaled, for reference
-            # loop-aware jaxpr FLOP/byte counts (cost_analysis counts scan
-            # bodies once; see launch/flopcount.py)
-            from repro.launch import flopcount
-            jx = flopcount.analyze(fn, *args)
             record.update(
                 status="ok",
                 n_devices=mesh.devices.size,
@@ -220,8 +216,6 @@ def main():
     ap.add_argument("--mesh", choices=["single", "multi", "both"],
                     default="single")
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--absorb-mla", action="store_true",
-                    help="decode with the absorbed-MLA optimization")
     ap.add_argument("--opt", action="store_true",
                     help="enable the beyond-paper §Perf optimizations "
                          "(shard_map flash-decode, tuned attn chunks)")
@@ -261,7 +255,7 @@ def main():
                     if json.load(f).get("status") in ("ok", "skip"):
                         continue
             rec = run_cell(arch, shape_name, mesh_name == "multi", args.out,
-                           absorb_mla=args.absorb_mla, tag=args.tag)
+                           tag=args.tag)
             if rec["status"] == "error":
                 failures += 1
     sys.exit(1 if failures else 0)

@@ -20,6 +20,7 @@ import dataclasses
 
 import jax
 
+from repro.configs.base import ModelConfig
 from repro.configs.base import reduced as reduced_config
 from repro.configs.registry import ARCHS, get_config
 from repro.core.engines import EngineSpec, default_engines
@@ -42,10 +43,12 @@ def engine_spec(arch: str) -> EngineSpec:
 
 
 def build_engine(arch: str, *, reduced: bool = False, seed: int = 0,
-                 max_len: int | None = None) -> InferenceEngine:
+                 max_len: int | None = None,
+                 cfg: ModelConfig | None = None) -> InferenceEngine:
     """One replica: seeded weights made on the device by one jitted init,
-    so no host copy of the parameters ever exists."""
-    cfg = get_config(arch)
+    so no host copy of the parameters ever exists.  ``cfg`` replaces the
+    registry's config of ``arch`` (a chip's share of it, say)."""
+    cfg = cfg or get_config(arch)
     if reduced:
         cfg = reduced_config(cfg)
     spec = engine_spec(arch)

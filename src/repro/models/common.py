@@ -9,6 +9,7 @@ and are swapped in on real hardware via ``cfg.use_pallas`` at the ops layer.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -56,12 +57,48 @@ def head_rms_norm(x, scale, eps=1e-6):
 # RoPE
 
 
-def rope_freqs(head_dim: int, theta: float, positions):
-    """positions: [...]; returns cos/sin of shape [..., head_dim/2]."""
+def rope_freqs(head_dim: int, theta: float, positions, yarn=None):
+    """positions: [...]; returns cos/sin of shape [..., head_dim/2].
+    ``yarn`` (a ``configs.base.YarnConfig``) rescales the frequencies."""
     half = head_dim // 2
     inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    mscale = 1.0
+    if yarn is not None:
+        inv = yarn_inv_freq(inv, head_dim, theta, yarn)
+        mscale = (yarn_mscale(yarn.factor, yarn.mscale)
+                  / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * inv
+    if mscale != 1.0:
+        return jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(inv, dim: int, theta: float, yarn):
+    """YaRN's blend of the original frequencies ``inv`` [dim/2] (kept for
+    the dims that turn more than ``beta_fast`` times over the original
+    context) and the same divided by ``factor`` (for those that turn
+    fewer than ``beta_slow`` times), with a linear ramp between."""
+
+    def turns_dim(turns):
+        return (dim * math.log(yarn.original_max_position
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inv / yarn.factor * (1.0 - keep) + inv * keep
 
 
 def apply_rope(x, cos, sin):
@@ -94,11 +131,12 @@ def _mask_bias(q_pos, k_pos, causal: bool, window: Optional[int]):
 
 
 def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                    k_valid=None):
+                    k_valid=None, scale=None):
     """Reference attention.  q: [B,Sq,H,hd], k/v: [B,Sk,K,hd] (GQA K|H).
 
     ``q_offset``: absolute position of q[0] (decode).  ``k_valid``: number of
-    valid kv entries (decode with a partially filled cache).
+    valid kv entries (decode with a partially filled cache).  ``scale``:
+    the softmax scale (default 1/sqrt(hd)).
     """
     B, Sq, H, hd = q.shape
     K = k.shape[2]
@@ -106,7 +144,11 @@ def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     G = H // K
     q = q.reshape(B, Sq, K, G, hd)
     scores = jnp.einsum("bqkgh,bskh->bkgqs", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(hd).astype(jnp.float32)
+                        k.astype(jnp.float32))
+    if scale is None:
+        scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+    else:
+        scores = scores * jnp.float32(scale)
     q_pos = q_offset + jnp.arange(Sq)
     k_pos = jnp.arange(k.shape[1])
     bias = _mask_bias(q_pos, k_pos, causal, window)
@@ -119,7 +161,8 @@ def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
 
 def chunked_flash_attention(q, k, v, *, causal=True, window=None,
-                            chunk=1024, q_offset=0, k_valid=None):
+                            chunk=1024, q_offset=0, k_valid=None,
+                            scale=None):
     """Online-softmax attention, scanning KV chunks — the XLA 'flash' path.
 
     Memory is O(Sq * chunk) instead of O(Sq * Sk); numerics match
@@ -128,18 +171,29 @@ def chunked_flash_attention(q, k, v, *, causal=True, window=None,
     Head-major layout: GQA k/v chunks are repeated to the full H query
     heads *inside* the scan (cheap — one chunk at a time), so the score and
     accumulator tensors keep a contiguous H dimension that GSPMD shards
-    over the ``model`` axis (inherited from the wq sharding).
+    over the ``model`` axis (inherited from the wq sharding).  One shared
+    kv head (MQA; MLA's latent) is never repeated: the heads are folded
+    into the query rows instead.
     """
     B, Sq, H, hd = q.shape
     Sk, K = k.shape[1], k.shape[2]
     hd_v = v.shape[-1]
     if Sk % chunk != 0:
         return naive_attention(q, k, v, causal=causal, window=window,
-                               q_offset=q_offset, k_valid=k_valid)
+                               q_offset=q_offset, k_valid=k_valid,
+                               scale=scale)
+    fold = H if K == 1 and H > 1 else 1
+    if fold > 1:    # query row s * H + h is head h at position s
+        q = q.reshape(B, Sq * H, 1, hd)
+        Sq, H = Sq * H, 1
     G = H // K
     qf = q.astype(jnp.float32)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-    q_pos = q_offset + jnp.arange(Sq)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    else:
+        scale = jnp.float32(scale)
+    q_pos = q_offset + (jnp.arange(Sq) // fold if fold > 1
+                        else jnp.arange(Sq))
 
     n_chunks = Sk // chunk
     ks = k.reshape(B, n_chunks, chunk, K, k.shape[-1])
@@ -182,18 +236,22 @@ def chunked_flash_attention(q, k, v, *, causal=True, window=None,
         (jnp.arange(n_chunks), ks.transpose(1, 0, 2, 3, 4),
          vs.transpose(1, 0, 2, 3, 4)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    out = out.transpose(0, 2, 1, 3).astype(q.dtype)
+    if fold > 1:
+        out = out.reshape(B, Sq // fold, fold, hd_v)
+    return out
 
 
 def attention(cfg: ModelConfig, q, k, v, *, causal=True, window=None,
-              q_offset=0, k_valid=None):
-    """Dispatch: chunked flash for long sequences, naive for short ones."""
+              q_offset=0, k_valid=None, scale=None):
+    """Dispatch: chunked flash for long sequences, naive for short ones.
+    ``scale`` is the softmax scale, 1/sqrt(head dim) by default."""
     if k.shape[1] >= cfg.flash_threshold:
         return chunked_flash_attention(q, k, v, causal=causal, window=window,
                                        chunk=cfg.attn_chunk, q_offset=q_offset,
-                                       k_valid=k_valid)
+                                       k_valid=k_valid, scale=scale)
     return naive_attention(q, k, v, causal=causal, window=window,
-                           q_offset=q_offset, k_valid=k_valid)
+                           q_offset=q_offset, k_valid=k_valid, scale=scale)
 
 
 # ----------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """Generic grouped decoder stack.
 
-Layers are described by a per-layer ``LayerSpec``; consecutive identical
+Layers are described by a per-layer ``LayerSpec`` (a MoE config's first
+``n_dense_layers`` are dense); consecutive identical
 specs are stacked (leading ``n`` dim) and executed with ``lax.scan`` so the
 HLO stays one-layer-sized regardless of depth (compile-time critical for the
 512-device dry-run).  Heterogeneous stacks (VLM cross-attn every 5th layer,
@@ -12,7 +13,8 @@ leaf.  A sequence buffer (attention k/v, sliding-window rings, MLA
 ckv/krope) takes its one new position at (i, :, pos) in the carried
 stack, and attention reads layer ``i`` of the written stack; a recurrent
 state (rwkv, mamba) replaces its layer whole; a cross-attention cache is
-read and carried on untouched.  The stack the scan ends with is the
+read and carried on untouched; a MoE layer adds the step's expert
+counts (``moe_load``, see ``expert_load``) to its row.  The stack the scan ends with is the
 decode step's output, so under the engine's donation the cache is
 written where it lies and never copied.
 """
@@ -62,10 +64,11 @@ def build_layout(cfg: ModelConfig) -> list[Group]:
                 i % cfg.vision.cross_attn_every == cfg.vision.cross_attn_every - 2):
             # cross layers at 3, 8, 13, ... for every=5
             specs.append(LayerSpec("cross"))
-        elif cfg.moe is not None:
+        elif cfg.moe is not None and i >= cfg.n_dense_layers:
             specs.append(LayerSpec("moe", window=window, mla=cfg.mla is not None))
         else:
-            specs.append(LayerSpec("dense", window=window))
+            specs.append(LayerSpec("dense", window=window,
+                                   mla=cfg.mla is not None))
     groups: list[Group] = []
     for s in specs:
         if groups and groups[-1].spec == s:
@@ -138,12 +141,16 @@ def _init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch, buf_len,
                 "cross": {"ck": jnp.zeros((batch, ctx_len, K, hd), dtype=dtype),
                           "cv": jnp.zeros((batch, ctx_len, K, hd), dtype=dtype)}}
     if spec.mla:
-        return layers.init_mla_cache(cfg, batch, buf, dtype)
-    return layers.init_attn_cache(cfg, batch, buf, dtype)
+        cache = layers.init_mla_cache(cfg, batch, buf, dtype)
+    else:
+        cache = layers.init_attn_cache(cfg, batch, buf, dtype)
+    if spec.kind == "moe":
+        cache["moe_load"] = layers.init_moe_load()
+    return cache
 
 
 def _layer_forward(p, cfg: ModelConfig, spec: LayerSpec, x, *, mode, cache,
-                   pos, ctx, absorb_mla=False):
+                   pos, ctx):
     cache = cache or {}
     if spec.kind == "rwkv":
         cache = layers.read_layer(cache)
@@ -207,8 +214,7 @@ def _layer_forward(p, cfg: ModelConfig, spec: LayerSpec, x, *, mode, cache,
     xin = rms_norm(x, p["ln1"], cfg.norm_eps)
     if spec.mla:
         h, a_cache = layers.mla_sublayer(p["attn"], cfg, xin, mode=mode,
-                                         cache=cache or None, pos=pos,
-                                         absorb=absorb_mla)
+                                         cache=cache or None, pos=pos)
     else:
         h, a_cache = layers.attn_sublayer(p["attn"], cfg, xin, mode=mode,
                                           cache=cache or None, pos=pos,
@@ -216,7 +222,12 @@ def _layer_forward(p, cfg: ModelConfig, spec: LayerSpec, x, *, mode, cache,
     x = x + h
     xin = rms_norm(x, p["ln2"], cfg.norm_eps)
     if spec.kind == "moe":
-        h = layers.moe_ffn(p["moe"], cfg, xin)
+        h, load = layers.moe_ffn(p["moe"], cfg, xin)
+        if mode == "prefill":
+            a_cache["moe_load"] = layers.init_moe_load().at[0].set(load)
+        elif mode == "decode":
+            a_cache["moe_load"] = layers.count_decode_load(
+                cache["moe_load"], load)
     else:
         h = common.mlp(p["mlp"], xin, cfg.act)
     x = x + h
@@ -254,7 +265,7 @@ def init_decoder_cache(cfg: ModelConfig, batch, buf_len, ctx_len=0):
 
 
 def decoder_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
-                  ctx=None, absorb_mla=False):
+                  ctx=None):
     """Run all layer groups.  x: [B, S, D] -> ([B, S, D], new_caches)."""
     from repro.distributed.sharding import constrain_seq
     groups = build_layout(cfg)
@@ -267,8 +278,7 @@ def decoder_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
                 # sequence-parallel residual stream (no-op off-mesh)
                 xc = constrain_seq(xc)
             y, new_c = _layer_forward(lp, cfg, _spec, xc, mode=mode,
-                                      cache=lcache, pos=pos, ctx=ctx,
-                                      absorb_mla=absorb_mla)
+                                      cache=lcache, pos=pos, ctx=ctx)
             return y, new_c
 
         if mode == "train" and cfg.remat:
@@ -298,6 +308,14 @@ def decoder_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
                 step, (x, gcache), (gparams, jnp.arange(g.n)))
             new_caches.append(new_c)
     return x, new_caches
+
+
+def expert_load(caches):
+    """The ``moe_load`` stacks of a decoder cache ([n, 2, 2] int32 per MoE
+    scan group: per layer, prefill then decode, the routed assignments
+    that landed on held experts and the held experts they touched)."""
+    return [c["moe_load"] for c in caches
+            if isinstance(c, dict) and "moe_load" in c]
 
 
 def _row_major(stacks):

@@ -82,6 +82,21 @@ def _cache_write(buf: StackedLayer, update, idx):
     return out, out.read()
 
 
+def init_moe_load():
+    """A MoE layer's ``held_load`` counts as its cache carries them: row 0
+    those of the prefill, row 1 those summed over the decode steps."""
+    return jnp.zeros((2, 2), jnp.int32)
+
+
+def count_decode_load(buf: StackedLayer, load):
+    """Add one decode step's ``held_load`` to row 1 of the layer ``buf``
+    views, in the stack the decode loop carries."""
+    layer = buf.read().at[1].add(load)
+    return StackedLayer(
+        jax.lax.dynamic_update_index_in_dim(buf.stack, layer, buf.i, 0),
+        buf.i)
+
+
 # =============================================================================
 # GQA self-attention sublayer (dense / moe / vlm-self / hymba-attn-branch)
 # =============================================================================
@@ -234,139 +249,274 @@ def init_mla_cache(cfg: ModelConfig, batch, buf_len, dtype):
     }
 
 
-def mla_sublayer(p, cfg: ModelConfig, x, *, mode, cache, pos,
-                 absorb: bool = False):
-    """MLA with a compressed latent cache.
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk_nope + qk_rope), times YaRN's mscale^2 where the rope is
+    scaled (DeepSeek-V2's ``softmax_scale``)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= common.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
-    ``absorb=False`` (paper-faithful baseline): decode re-expands k/v from the
-    latent via wk_b/wv_b each step.  ``absorb=True`` (§Perf optimization):
-    wk_b is absorbed into the query and wv_b into the output projection so
-    decode attends directly in the rank-512 latent space.
+
+def mla_sublayer(p, cfg: ModelConfig, x, *, mode, cache, pos):
+    """MLA with a compressed latent cache (DeepSeek-V2).
+
+    The cache holds, per position, the normed latent ``ckv``
+    (kv_lora_rank) and the roped shared key ``krope`` (qk_rope_head_dim).
+    Train and prefill re-expand k and v per head from the latent through
+    ``wk_b``/``wv_b``.  Decode attends in the latent space: ``wk_b`` is
+    folded into the query and ``wv_b`` into the output, so the query
+    heads read the one shared latent head of the cache and no per-head k
+    or v exists.  Both use the published softmax scale
+    (``mla_softmax_scale``) and YaRN rope on the rope dims.
     """
     m = cfg.mla
     B, S, D = x.shape
-    H = cfg.n_heads
-    nope, rope_d, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    nope, rope_d = m.qk_nope_head_dim, m.qk_rope_head_dim
 
-    q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
-    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", q, p["wq_b"])
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    with jax.named_scope("mla.project"):
+        q = jnp.einsum("bsd,dr->bsr", x, p["wq_a"])
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", q, p["wq_b"])
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
 
-    kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
-    ckv = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_rope = kv_a[..., m.kv_lora_rank:]
+        kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])
+        ckv = rms_norm(kv_a[..., :m.kv_lora_rank], p["kv_norm"],
+                       cfg.norm_eps)
+        k_rope = kv_a[..., m.kv_lora_rank:]
 
-    if mode == "decode":
-        positions = jnp.zeros((S,), jnp.int32) + pos
-    else:
-        positions = jnp.arange(S)
-    cos, sin = rope_freqs(rope_d, cfg.rope_theta, positions)
-    q_rope = apply_rope(q_rope, cos, sin)
-    k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
+        if mode == "decode":
+            positions = jnp.zeros((S,), jnp.int32) + pos
+        else:
+            positions = jnp.arange(S)
+        cos, sin = rope_freqs(rope_d, cfg.rope_theta, positions,
+                              cfg.rope_scaling)
+        q_rope = apply_rope(q_rope, cos, sin)
+        k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)[:, :, 0, :]
+    scale = mla_softmax_scale(cfg)
 
     if mode == "decode":
         new_ckv, ckv_full = _cache_write(cache["ckv"], ckv, pos)
         new_krope, krope_full = _cache_write(cache["krope"], k_rope, pos)
         new_cache = {"ckv": new_ckv, "krope": new_krope}
-        k_valid = pos + 1
-        causal = False
-    else:
-        ckv_full, krope_full = ckv, k_rope
-        new_cache = ({"ckv": ckv, "krope": k_rope}
-                     if mode == "prefill" else None)
-        k_valid = None
-        causal = True
-
-    if absorb and mode == "decode":
-        # fold wk_b into q: q_lat [B,1,H,R]; attend in latent space.
-        q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"])
-        q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)
-        k_cat = jnp.concatenate(
-            [ckv_full, krope_full], axis=-1)[:, :, None, :]  # MQA: 1 kv head
-        out_lat = None
-        if SHARDED_DECODE_ATTN:
-            from repro.distributed.sharding import sharded_decode_attention
-            out_lat = sharded_decode_attention(
-                q_cat, k_cat, ckv_full[:, :, None, :], k_valid)
-        if out_lat is None:
-            out_lat = attention(cfg, q_cat, k_cat, ckv_full[:, :, None, :],
-                                causal=False, k_valid=k_valid)
-        # out in latent space -> expand through wv_b folded with wo
-        y = jnp.einsum("bshr,rhv,hvd->bsd", out_lat, p["wv_b"], p["wo"])
+        with jax.named_scope("mla.project"):
+            q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"])
+            q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)
+        with jax.named_scope("mla.latent_attend"):
+            # one latent head shared by every query head (MQA)
+            k_cat = jnp.concatenate([ckv_full, krope_full],
+                                    axis=-1)[:, :, None, :]
+            v_lat = ckv_full[:, :, None, :]
+            out_lat = None
+            if SHARDED_DECODE_ATTN:     # it scales by 1/sqrt(head dim)
+                from repro.distributed.sharding import \
+                    sharded_decode_attention
+                out_lat = sharded_decode_attention(
+                    q_cat * (scale * q_cat.shape[-1] ** 0.5), k_cat, v_lat,
+                    pos + 1)
+            if out_lat is None:
+                out_lat = attention(cfg, q_cat, k_cat, v_lat, causal=False,
+                                    k_valid=pos + 1, scale=scale)
+        with jax.named_scope("mla.project"):
+            out = jnp.einsum("bshr,rhv->bshv", out_lat, p["wv_b"])
+            y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
         return y, new_cache
 
-    k_nope = jnp.einsum("bsr,rhk->bshk", ckv_full, p["wk_b"])
-    v = jnp.einsum("bsr,rhv->bshv", ckv_full, p["wv_b"])
-    k_rope_h = jnp.broadcast_to(krope_full[:, :, None, :],
-                                k_nope.shape[:3] + (rope_d,))
-    k = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    q_cat = jnp.concatenate([q_nope, q_rope], axis=-1)
-    out = attention(cfg, q_cat, k, v, causal=causal, k_valid=k_valid)
-    y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
+    new_cache = {"ckv": ckv, "krope": k_rope} if mode == "prefill" else None
+    with jax.named_scope("mla.project"):
+        k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["wk_b"])
+        v = jnp.einsum("bsr,rhv->bshv", ckv, p["wv_b"])
+        k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :],
+                                    k_nope.shape[:3] + (rope_d,))
+        k = jnp.concatenate([k_nope, k_rope_h], axis=-1)
+        q_cat = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with jax.named_scope("mla.attend"):
+        out = attention(cfg, q_cat, k, v, causal=True, scale=scale)
+    with jax.named_scope("mla.project"):
+        y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
     return y, new_cache
 
 
 # =============================================================================
-# MoE FFN — GShard-style capacity dispatch, chunked over tokens
+# MoE FFN: group-limited routing; dropless over the held experts on one
+# device, GShard capacity dispatch on a mesh
 # =============================================================================
 
-MOE_CHUNK = 256  # tokens per dispatch group (baseline; §Perf iterates on this)
+MOE_CHUNK = 256  # tokens per dispatch group (capacity path)
+MOE_ROWS = 8192  # routed rows one pass of the held experts computes
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
+    """Router over all ``n_experts``; weights of the held experts only.
+    Expert e's weights are drawn from its own key (the e-th of
+    ``n_experts``), so a share's weights are the uncut layer's rows."""
     e = cfg.moe
     ff = e.d_ff_expert or cfg.d_ff
+    first, held = e.held
     ks = jax.random.split(key, 5)
     D = cfg.d_model
+
+    def experts(k, shape, in_axis):
+        keys = jax.random.split(k, e.n_experts)[first:first + held]
+        return jax.vmap(lambda kk: dense_init(kk, shape, in_axis=in_axis,
+                                              dtype=dtype))(keys)
+
     p = {
         "router": dense_init(ks[0], (D, e.n_experts), in_axis=0,
                              dtype=jnp.float32),
-        "wi": dense_init(ks[1], (e.n_experts, D, ff), in_axis=1, dtype=dtype),
-        "wg": dense_init(ks[2], (e.n_experts, D, ff), in_axis=1, dtype=dtype),
-        "wo": dense_init(ks[3], (e.n_experts, ff, D), in_axis=-1, dtype=dtype),
+        "wi": experts(ks[1], (D, ff), 0),
+        "wg": experts(ks[2], (D, ff), 0),
+        "wo": experts(ks[3], (ff, D), -2),
     }
     if e.n_shared:
         p["shared"] = common.init_mlp(ks[4], D, e.n_shared * ff, dtype)
     return p
 
 
-def _route(p, cfg: ModelConfig, xf):
-    """xf: [T, D] -> (gates [T, E] f32 with zeros off top-k, mask [T, E])."""
+def route(p, cfg: ModelConfig, x):
+    """x: [..., D] -> (weights [..., top_k] f32, experts [..., top_k]).
+
+    Softmax over every router logit in f32.  With ``n_group`` > 1 a
+    group's score is its best expert's, and only the experts of the
+    ``topk_group`` best groups can be chosen (DeepSeek-V2's
+    ``group_limited_greedy``).  The chosen scores are renormalised
+    (``norm_topk_prob``) or else scaled by ``routed_scaling_factor``."""
     e = cfg.moe
-    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, e.top_k)
-    mask = jax.nn.one_hot(top_idx, e.n_experts, dtype=jnp.float32).sum(axis=1)
-    gates = probs * mask
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    return gates, mask
+    # HIGHEST: at the default precision the TPU rounds both f32 operands
+    # to bf16, which flips choices between near-tied experts
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    if e.n_group > 1:
+        groups = scores.reshape(scores.shape[:-1] + (e.n_group, -1))
+        _, best = jax.lax.top_k(groups.max(axis=-1), e.topk_group)
+        kept = jax.nn.one_hot(best, e.n_group, dtype=jnp.bool_).any(axis=-2)
+        scores = jnp.where(kept[..., None], groups, 0.0).reshape(
+            scores.shape)
+    w, idx = jax.lax.top_k(scores, e.top_k)
+    if e.norm_topk_prob:
+        w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    else:
+        w = w * e.routed_scaling_factor
+    return w, idx
 
 
-def _route_grouped(p, cfg: ModelConfig, xg):
-    """xg: [B, G, T, D] -> (gates, mask) [B, G, T, E] f32."""
-    e = cfg.moe
-    logits = jnp.einsum("bgtd,de->bgte", xg.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, e.top_k)
-    mask = jax.nn.one_hot(top_idx, e.n_experts, dtype=jnp.float32).sum(axis=-2)
-    gates = probs * mask
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    return gates, mask
+def _held_counts(cfg: ModelConfig, idx):
+    """For expert choices ``idx``: the held expert of each (its index in
+    the share, or ``held`` where it is not held), flat, and the count of
+    choices of each held expert."""
+    first, held = cfg.moe.held
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    return key, jnp.zeros((held,), jnp.int32).at[key].add(1, mode="drop")
+
+
+def held_load(cfg: ModelConfig, idx):
+    """[routed assignments that land on held experts, held experts with
+    at least one of them] (int32) for expert choices ``idx``."""
+    _, counts = _held_counts(cfg, idx)
+    return jnp.stack([counts.sum(), (counts > 0).sum().astype(jnp.int32)])
 
 
 def moe_ffn(p, cfg: ModelConfig, x):
+    """The routed experts' part of the output (of the held experts only)
+    plus the shared experts; returns (out [B, S, D], ``held_load``).
+
+    Off a mesh (the serving engine on one device) every routed assignment
+    to a held expert is computed, none dropped (``_experts_dropless``).
+    On a mesh (the dry-run) the GShard capacity dispatch shards the
+    experts over 'model' and holds all of them (``_experts_capacity``):
+    the dropless layer compiles there too, but GSPMD does not partition
+    its grouped products, and a training step's temporaries grow several
+    times over.
+    """
+    from repro.distributed.sharding import get_active_mesh
+    e = cfg.moe
+    B, S, D = x.shape
+    with jax.named_scope("moe.route"):
+        w, idx = route(p, cfg, x)
+        load = held_load(cfg, idx)
+    with jax.named_scope("moe.experts"):
+        if get_active_mesh() is None:
+            out = _experts_dropless(p, cfg, x.reshape(B * S, D),
+                                    w.reshape(B * S, -1),
+                                    idx.reshape(B * S, -1)).reshape(B, S, D)
+        else:
+            out = _experts_capacity(p, cfg, x, w, idx)
+    if e.n_shared:
+        with jax.named_scope("moe.shared"):
+            out = out + common.mlp(p["shared"], x, cfg.act)
+    return out, load
+
+
+def _experts_dropless(p, cfg: ModelConfig, xf, w, idx):
+    """xf: [T, D]; w, idx: [T, k].  The held experts' weighted outputs
+    summed per token, [T, D], without drops.
+
+    The T * k assignments are sorted by expert (those to experts not held
+    last) and run through the held experts as one grouped matrix product
+    (``lax.ragged_dot``) per pass of up to ``MOE_ROWS`` rows; the passes
+    stop after the last held assignment, so a held expert computes only
+    the tokens routed to it and the absent experts cost nothing."""
+    T, k = idx.shape
+    key, counts = _held_counts(cfg, idx)
+    order = jnp.argsort(key, stable=True)
+    tok = order // k
+    wt = jnp.where(key < counts.shape[0], w.reshape(-1), 0.0)[order]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n = ends[-1]
+    M = T * k
+    C = min(M, MOE_ROWS)
+    passes = -(-M // C)
+    tok = jnp.pad(tok, (0, passes * C - M))
+    wt = jnp.pad(wt, (0, passes * C - M))
+
+    def one_pass(c, out):
+        lo = c * C
+        t = jax.lax.dynamic_slice_in_dim(tok, lo, C)
+        wc = jax.lax.dynamic_slice_in_dim(wt, lo, C)
+        sizes = (jnp.clip(ends, lo, lo + C) - jnp.clip(starts, lo, lo + C))
+        xs = xf[t]
+        a = jax.lax.ragged_dot(xs, p["wi"], sizes)
+        g = jax.lax.ragged_dot(xs, p["wg"], sizes)
+        act = jax.nn.gelu(g) if cfg.act == "gelu" else jax.nn.silu(g)
+        y = jax.lax.ragged_dot(a * act, p["wo"], sizes)
+        valid = lo + jnp.arange(C) < n
+        y = jnp.where(valid[:, None], y.astype(jnp.float32) * wc[:, None],
+                      0.0)
+        return out.at[t].add(y)
+
+    out = jnp.zeros(xf.shape, jnp.float32)
+    if passes == 1:
+        out = one_pass(0, out)
+    else:
+        # a static count of passes keeps the layer differentiable, as
+        # training needs; a pass past the last held assignment is skipped
+        skip = lambda c, o: o
+        out, _ = jax.lax.scan(
+            lambda o, c: (jax.lax.cond(c * C < n, one_pass, skip, c, o),
+                          None), out, jnp.arange(passes))
+    return out.astype(xf.dtype)
+
+
+def _experts_capacity(p, cfg: ModelConfig, x, w, idx):
     """GShard grouped capacity dispatch (Mesh-TF/GSPMD formulation).
 
     Tokens keep their [B, S] layout: the sequence is split into groups of
     MOE_CHUNK tokens and each group dispatches into per-expert capacity
     buffers via dense one-hot einsums.  The group structure (instead of a
     flat token lax.map) is what keeps every tensor shardable: batch stays
-    on the DP axes and the expert axis is constrained onto 'model'.
+    on the DP axes and the expert axis is constrained onto 'model'.  A
+    token past its expert's capacity is dropped.
     """
     from repro.distributed.sharding import (constrain_moe_expert,
                                             constrain_moe_groups)
     e = cfg.moe
+    if e.held[1] != e.n_experts:
+        raise ValueError("the capacity dispatch holds every expert")
     B, S, D = x.shape
     g = min(MOE_CHUNK, S)
     if S % g:
@@ -375,7 +525,10 @@ def moe_ffn(p, cfg: ModelConfig, x):
     capacity = max(e.top_k, int(g / e.n_experts * e.top_k
                                 * e.capacity_factor))
     xg = constrain_moe_groups(x.reshape(B, G, g, D))
-    gates, mask = _route_grouped(p, cfg, xg)
+    onehot = jax.nn.one_hot(idx.reshape(B, G, g, -1), e.n_experts,
+                            dtype=jnp.float32)
+    mask = onehot.sum(axis=-2)                            # [B,G,T,E]
+    gates = (onehot * w.reshape(B, G, g, -1, 1)).sum(axis=-2)
     # position of each token within its expert's capacity buffer (per group)
     pos_in_exp = jnp.cumsum(mask, axis=2) - 1.0
     keep = mask * (pos_in_exp < capacity)
@@ -394,10 +547,7 @@ def moe_ffn(p, cfg: ModelConfig, x):
     exp_out = constrain_moe_expert(
         jnp.einsum("bgecf,efd->bgecd", a * act, p["wo"]))
     out = jnp.einsum("bgtec,bgecd->bgtd", combine.astype(dt), exp_out)
-    out = constrain_moe_groups(out.reshape(B, G, g, D)).reshape(B, S, D)
-    if e.n_shared:
-        out = out + common.mlp(p["shared"], x, cfg.act)
-    return out
+    return constrain_moe_groups(out.reshape(B, G, g, D)).reshape(B, S, D)
 
 
 # =============================================================================

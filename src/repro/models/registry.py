@@ -6,6 +6,7 @@ things the launcher lowers:
     train_loss(params, batch)            -> scalar loss
     prefill(params, batch)               -> (last_logits [B,V], caches)
     decode(params, caches, batch)        -> (logits [B,V], caches)
+    expert_load(caches)                  -> per-layer expert counters
     init_params(key)                     -> pytree
     init_cache(batch, buf_len)           -> caches pytree
     input_specs(shape_cell)              -> batch pytree of ShapeDtypeStruct
@@ -76,6 +77,8 @@ class Model:
     decode: Callable[[Any, Any, Any], Any]
     init_cache: Callable[[int, int], Any]
     input_specs: Callable[[ShapeCell], Any]
+    # the cache's per-layer expert counters (``decoder.expert_load``)
+    expert_load: Callable[[Any], list] = lambda caches: []
 
 
 # ----------------------------------------------------------------------------
@@ -98,19 +101,28 @@ def _build_decoder_model(cfg: ModelConfig) -> Model:
                                      ctx=_ctx_of(cfg, batch))
         return chunked_ce_loss(params, cfg, x, batch["labels"])
 
-    def prefill(params, batch, absorb_mla=False):
+    def prefill_all(params, batch):
         x = common.embed(params["embed"], cfg, batch["tokens"])
         x, caches = decoder.decoder_stack(params, cfg, x, mode="prefill",
-                                          ctx=_ctx_of(cfg, batch),
-                                          absorb_mla=absorb_mla)
+                                          ctx=_ctx_of(cfg, batch))
         logits = common.unembed(params["embed"], cfg, x[:, -1:, :])
         return logits[:, 0, :], caches
 
-    def decode(params, caches, batch, absorb_mla=False):
+    def prefill(params, batch):
+        B, S = batch["tokens"].shape
+        if cfg.prefill_tokens and B * S > cfg.prefill_tokens:
+            # the most rows that divide the batch and fit the tokens
+            rows = max([r for r in range(1, B + 1)
+                        if B % r == 0 and r * S <= cfg.prefill_tokens]
+                       or [1])
+            return prefill_by_rows(prefill_all, params, batch, rows)
+        return prefill_all(params, batch)
+
+    def decode(params, caches, batch):
         x = common.embed(params["embed"], cfg, batch["token"])
         x, caches = decoder.decoder_stack(params, cfg, x, mode="decode",
                                           caches=caches, pos=batch["pos"],
-                                          ctx=None, absorb_mla=absorb_mla)
+                                          ctx=None)
         logits = common.unembed(params["embed"], cfg, x)
         return logits[:, 0, :], caches
 
@@ -136,7 +148,42 @@ def _build_decoder_model(cfg: ModelConfig) -> Model:
         return batch
 
     return Model(cfg, init_params, train_loss, prefill, decode, init_cache,
-                 input_specs)
+                 input_specs, decoder.expert_load)
+
+
+def prefill_by_rows(prefill, params, batch, rows: int):
+    """``prefill`` of a batch taken ``rows`` rows at a time inside one
+    program, so that its activations are those of ``rows`` rows.  Rows are
+    independent (dropless experts), so the logits and caches are those of
+    the whole batch: each block's are written into the whole batch's
+    buffers at its rows, and its ``moe_load`` counts are summed."""
+    B = batch["tokens"].shape[0]
+    if B % rows:
+        raise ValueError(f"prefill of {B} rows in blocks of {rows}")
+    block = lambda b: jax.tree.map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, b * rows, rows, 0), batch)
+    shapes = jax.eval_shape(prefill, params, block(0))
+    counted = lambda path: path[-1] == jax.tree_util.DictKey("moe_load")
+    # logits [rows, V] take rows on axis 0; stacked cache leaves
+    # [n, rows, ...] on axis 1
+    axis = lambda path: 0 if len(path) == 1 else 1
+
+    def whole(path, s):
+        if counted(path):
+            return jnp.zeros(s.shape, s.dtype)
+        shape = list(s.shape)
+        shape[axis(path)] = B
+        return jnp.zeros(shape, s.dtype)
+
+    def body(b, acc):
+        out = prefill(params, block(b))
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a, o: a + o if counted(path) else
+            jax.lax.dynamic_update_slice_in_dim(a, o, b * rows, axis(path)),
+            acc, out)
+
+    init = jax.tree_util.tree_map_with_path(whole, shapes)
+    return jax.lax.fori_loop(0, B // rows, body, init)
 
 
 # ----------------------------------------------------------------------------

@@ -9,8 +9,9 @@ profiler's clock, nested on the calling thread: ``engine.generate`` for a
 whole request (its ``request`` ordinal, ``rows``, ``prompt_len`` and
 ``tokens``), and inside it ``engine.prefill``, ``engine.sample`` for each
 token, ``engine.decode_step`` for each step after the first token (its
-sampling nested in it) and ``engine.wait`` for the final sync and the
-stacking of the tokens.
+sampling nested in it) and ``engine.wait`` for the final sync, the
+stacking of the tokens and, for mixture-of-experts models, the one read
+of the cache's expert counters into ``EngineStats``.
 """
 
 from __future__ import annotations
@@ -26,11 +27,27 @@ from repro.serving import sampling
 from repro.serving.kvcache import cache_bytes, pad_cache
 
 
+def _by_phase():
+    return {"prefill": 0, "decode": 0}
+
+
 @dataclasses.dataclass
 class EngineStats:
     prefill_tokens: int = 0
     decoded_tokens: int = 0
     batches: int = 0
+    # mixture-of-experts models, summed over layers and requests, by
+    # phase: routed assignments that landed on the experts the engine
+    # holds, and held experts touched (once per layer per program call)
+    held_assignments: dict = dataclasses.field(default_factory=_by_phase)
+    experts_touched: dict = dataclasses.field(default_factory=_by_phase)
+
+    def add_expert_load(self, loads):
+        """``loads``: the cache's ``moe_load`` stacks, on the host."""
+        for load in loads:
+            for row, phase in enumerate(("prefill", "decode")):
+                self.held_assignments[phase] += int(load[:, row, 0].sum())
+                self.experts_touched[phase] += int(load[:, row, 1].sum())
 
 
 class InferenceEngine:
@@ -96,6 +113,9 @@ class InferenceEngine:
             with jax.profiler.TraceAnnotation("engine.wait"):
                 jax.block_until_ready(tok)
                 out = jnp.stack(outs, axis=1)
+                loads = self.model.expert_load(caches)
+                if loads:
+                    self.stats.add_expert_load(jax.device_get(loads))
             self.stats.decoded_tokens += B * n_tokens
             return out
 

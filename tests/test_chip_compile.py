@@ -154,3 +154,119 @@ def test_qwen3_4b_decode_writes_cache_in_place(one_chip, no_compile_cache,
     assert all(shapes[u] == one for u in into_stack), \
         [shapes[u] for u in into_stack]
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.fixture(scope="module")
+def deepseek_share(one_chip):
+    """The model and parameter shapes of one chip's share of DeepSeek-V2
+    as the benchmark's ``deepseek-v2.decode`` cell runs it: the first 7
+    layers, experts 0-15 of each expert layer, prefill by blocks of 2,048
+    tokens."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs.registry import get_config
+    from repro.models.registry import build_model
+
+    cfg = get_config("deepseek-v2-236b")
+    cfg = dataclasses.replace(cfg, n_layers=7, prefill_tokens=2048,
+                              moe=dataclasses.replace(cfg.moe, n_held=16))
+    model = build_model(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    params = place(jax.eval_shape(model.init_params,
+                                  jax.random.PRNGKey(0)))
+    return cfg, model, params, place
+
+
+def _need(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def test_deepseek_v2_decode_attends_in_the_latent_space(
+        deepseek_share, no_compile_cache):
+    """The 64-row decode step over 2,560 positions: no array holds the
+    latent (576 wide) or a key (192 wide) for each of the 128 query heads
+    at many positions (a chunk of 512 or the whole cache), as repeating
+    the latent per head or re-expanding keys would; and the step fits
+    one chip."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, model, params, place = deepseek_share
+    B, L = 64, 2560
+    caches = place(jax.eval_shape(lambda: model.init_cache(B, L)))
+    step = place({"token": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                  "pos": jax.ShapeDtypeStruct((), jnp.int32)})
+    compiled = jax.jit(model.decode, donate_argnums=(1,)).lower(
+        params, caches, step).compile()
+    shapes = set(_hlo_shapes(compiled.as_text()).values())
+    seq = {cfg.attn_chunk, L}
+    per_head = [s for s in shapes if len(s) >= 3 and s[0] == B
+                and 128 in s[1:] and {576, 192} & set(s[1:])
+                and seq & set(s[1:])]
+    assert per_head == [], sorted(per_head)[:5]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"deepseek-v2 decode {B}x{L}: temp {temp} B")
+    assert temp < 2e9
+    assert _need(compiled) < V5E_HBM_BYTES
+
+
+def test_deepseek_v2_prefill_by_blocks_fits(deepseek_share,
+                                            no_compile_cache):
+    """A 64 x 2,048 prompt prefills as one program by blocks of 2,048
+    tokens: its activations stay near 2 GB beside the 9.68 GB of
+    weights and the 1.07 GB of latent cache it writes."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, model, params, place = deepseek_share
+    tok = place(jax.ShapeDtypeStruct((64, 2048), jnp.int32))
+    compiled = jax.jit(model.prefill).lower(params, {"tokens": tok}).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"deepseek-v2 prefill 64x2048: temp {temp} B")
+    assert temp < 3e9
+    assert _need(compiled) < V5E_HBM_BYTES - 2 * 1.33e9
+
+
+# temp bytes of qwen3-4b's programs at the benchmark cells' shapes, as
+# compiled before the MLA and expert layers changed the shared attention
+# and decoder code; the qwen3-4b programs must not move
+QWEN3_4B_TEMP = {("prefill", 4, 2048): 1_024_669_184,
+                 ("prefill", 1, 4096): 315_315_712,
+                 ("decode", 4, 2560): 581_120,
+                 ("decode", 1, 4224): 354_816}
+
+
+@pytest.mark.parametrize("kind,B,L", sorted(QWEN3_4B_TEMP),
+                         ids=lambda v: str(v))
+def test_qwen3_4b_programs_keep_their_temp_bytes(one_chip, no_compile_cache,
+                                                 kind, B, L):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs.registry import get_config
+    from repro.models.registry import build_model
+
+    model = build_model(get_config("qwen3-4b"))
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    params = place(jax.eval_shape(model.init_params,
+                                  jax.random.PRNGKey(0)))
+    if kind == "prefill":
+        tok = place(jax.ShapeDtypeStruct((B, L), jnp.int32))
+        compiled = jax.jit(model.prefill).lower(
+            params, {"tokens": tok}).compile()
+    else:
+        caches = place(jax.eval_shape(lambda: model.init_cache(B, L)))
+        step = place({"token": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                      "pos": jax.ShapeDtypeStruct((), jnp.int32)})
+        compiled = jax.jit(model.decode, donate_argnums=(1,)).lower(
+            params, caches, step).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes == \
+        QWEN3_4B_TEMP[(kind, B, L)]
