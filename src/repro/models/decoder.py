@@ -17,6 +17,11 @@ read and carried on untouched; a MoE layer adds the step's expert
 counts (``moe_load``, see ``expert_load``) to its row.  The stack the scan ends with is the
 decode step's output, so under the engine's donation the cache is
 written where it lies and never copied.
+
+Off a mesh, the inference scans (prefill and decode) likewise take a MoE
+group's held-expert stacks out of the scanned parameters and hand layer
+``i`` ``StackedLayer`` views of them, which the dropless expert layer's
+grouped products read in place (``layers._grouped_weights``).
 """
 
 from __future__ import annotations
@@ -290,22 +295,31 @@ def decoder_stack(params, cfg: ModelConfig, x, *, mode, caches=None, pos=None,
                 lambda xc, lp: (body(xc, (lp, None))[0], None), x, gparams)
             new_caches.append(None)
         elif gcache is None:  # prefill: caches are produced, not consumed
-            x, new_c = jax.lax.scan(
-                lambda xc, lp: body(xc, (lp, None)), x, gparams)
+            experts, rest = _held_experts(gparams, g.spec)
+            if experts is None:
+                x, new_c = jax.lax.scan(
+                    lambda xc, lp: body(xc, (lp, None)), x, gparams)
+            else:
+                x, new_c = jax.lax.scan(
+                    lambda xc, li: body(
+                        xc, (_view_experts(*li, experts), None)),
+                    x, (rest, jnp.arange(g.n)))
             new_caches.append(new_c)
         else:  # decode: the stacked caches ride in the loop's carry
-            def step(carry, layer_in, _body=body):
+            experts, rest = _held_experts(gparams, g.spec)
+
+            def step(carry, layer_in, _body=body, _e=experts):
                 xc, stacks = carry
                 stacks = _row_major(stacks)
                 lp, i = layer_in
                 views = jax.tree.map(lambda s: layers.StackedLayer(s, i),
                                      stacks)
-                y, new_c = _body(xc, (lp, views))
+                y, new_c = _body(xc, (_view_experts(lp, i, _e), views))
                 return (y, jax.tree.map(lambda s, c: _commit(s, c, i),
                                         stacks, new_c)), None
 
             (x, new_c), _ = jax.lax.scan(
-                step, (x, gcache), (gparams, jnp.arange(g.n)))
+                step, (x, gcache), (rest, jnp.arange(g.n)))
             new_caches.append(new_c)
     return x, new_caches
 
@@ -316,6 +330,30 @@ def expert_load(caches):
     that landed on held experts and the held experts they touched)."""
     return [c["moe_load"] for c in caches
             if isinstance(c, dict) and "moe_load" in c]
+
+
+def _held_experts(gparams, spec: LayerSpec):
+    """(the held experts' stacks, the group's other parameters) for an
+    inference scan over a group's layers.  Off a mesh a MoE group's
+    ``moe.wi``, ``wg``, ``wo`` ([n, E_held, ...]) leave the scan's
+    inputs, and layer i gets ``StackedLayer`` views of them
+    (``_view_experts``), which the dropless expert layer reads in place.
+    On a mesh, or without experts, (None, gparams)."""
+    from repro.distributed.sharding import get_active_mesh
+    if spec.kind != "moe" or get_active_mesh() is not None:
+        return None, gparams
+    moe = dict(gparams["moe"])
+    experts = {k: moe.pop(k) for k in ("wi", "wg", "wo")}
+    return experts, dict(gparams, moe=moe)
+
+
+def _view_experts(lp, i, experts):
+    """Layer ``i``'s parameters ``lp`` with ``StackedLayer`` views of the
+    held experts' stacks (``lp`` itself where ``experts`` is None)."""
+    if experts is None:
+        return lp
+    views = {k: layers.StackedLayer(s, i) for k, s in experts.items()}
+    return dict(lp, moe=dict(lp["moe"], **views))
 
 
 def _row_major(stacks):

@@ -40,9 +40,9 @@ SHARDED_DECODE_ATTN = False
 
 
 class StackedLayer(NamedTuple):
-    """Layer ``i`` of a cache leaf stacked over a scan group's layers
-    (``stack`` is [n, B, ...]): what the decoder's decode loop hands a
-    sublayer in place of the layer's own buffer."""
+    """Layer ``i`` of an array stacked over a scan group's layers (a
+    cache leaf, [n, B, ...], or held experts, [n, E, ...]): what the
+    decoder's loops hand a sublayer in place of the layer's own array."""
     stack: jax.Array
     i: jax.Array
 
@@ -459,8 +459,11 @@ def _experts_dropless(p, cfg: ModelConfig, xf, w, idx):
     last) and run through the held experts as one grouped matrix product
     (``lax.ragged_dot``) per pass of up to ``MOE_ROWS`` rows; the passes
     stop after the last held assignment, so a held expert computes only
-    the tokens routed to it and the absent experts cost nothing."""
+    the tokens routed to it and the absent experts cost nothing.  The
+    weights are arrays, or ``StackedLayer`` views of the decoder's
+    stacked experts (``_grouped_weights``)."""
     T, k = idx.shape
+    (wi, wg, wo), place = _grouped_weights(p)
     key, counts = _held_counts(cfg, idx)
     order = jnp.argsort(key, stable=True)
     tok = order // k
@@ -478,12 +481,13 @@ def _experts_dropless(p, cfg: ModelConfig, xf, w, idx):
         lo = c * C
         t = jax.lax.dynamic_slice_in_dim(tok, lo, C)
         wc = jax.lax.dynamic_slice_in_dim(wt, lo, C)
-        sizes = (jnp.clip(ends, lo, lo + C) - jnp.clip(starts, lo, lo + C))
+        sizes = place(jnp.clip(ends, lo, lo + C)
+                      - jnp.clip(starts, lo, lo + C))
         xs = xf[t]
-        a = jax.lax.ragged_dot(xs, p["wi"], sizes)
-        g = jax.lax.ragged_dot(xs, p["wg"], sizes)
+        a = jax.lax.ragged_dot(xs, wi, sizes)
+        g = jax.lax.ragged_dot(xs, wg, sizes)
         act = jax.nn.gelu(g) if cfg.act == "gelu" else jax.nn.silu(g)
-        y = jax.lax.ragged_dot(a * act, p["wo"], sizes)
+        y = jax.lax.ragged_dot(a * act, wo, sizes)
         valid = lo + jnp.arange(C) < n
         y = jnp.where(valid[:, None], y.astype(jnp.float32) * wc[:, None],
                       0.0)
@@ -500,6 +504,27 @@ def _experts_dropless(p, cfg: ModelConfig, xf, w, idx):
             lambda o, c: (jax.lax.cond(c * C < n, one_pass, skip, c, o),
                           None), out, jnp.arange(passes))
     return out.astype(xf.dtype)
+
+
+def _grouped_weights(p):
+    """The held experts' ``wi``, ``wg``, ``wo`` as ``lax.ragged_dot``'s
+    groups, and what turns a pass's sizes of the held experts into its
+    sizes of those groups.
+
+    ``StackedLayer`` views of layer i of [n, E, ...] stacks are read
+    where the stacks lie: each stack is n * E groups (for a row-major
+    stack the reshape is a bitcast) and the held experts' sizes go at
+    i * E, the other groups empty.  The kernel is a custom call, which
+    cannot read a slice of its operand in place, so handing it layer i
+    alone would copy the layer's experts."""
+    ws = [p[k] for k in ("wi", "wg", "wo")]
+    if not isinstance(ws[0], StackedLayer):
+        return ws, lambda sizes: sizes
+    n, E = ws[0].stack.shape[:2]
+    i = ws[0].i
+    flat = [w.stack.reshape((n * E,) + w.stack.shape[2:]) for w in ws]
+    return flat, lambda sizes: jax.lax.dynamic_update_slice(
+        jnp.zeros((n * E,), sizes.dtype), sizes, (i * E,))
 
 
 def _experts_capacity(p, cfg: ModelConfig, x, w, idx):

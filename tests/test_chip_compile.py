@@ -233,6 +233,48 @@ def test_deepseek_v2_prefill_by_blocks_fits(deepseek_share,
     assert _need(compiled) < V5E_HBM_BYTES - 2 * 1.33e9
 
 
+# decode temp of the share's 64 x 2,560 step while each expert layer's
+# held experts were copied out of the layer stack for the grouped products
+DEEPSEEK_DECODE_TEMP_WITH_COPIES = 1_113_846_272
+
+
+def test_deepseek_v2_programs_read_the_held_experts_in_place(
+        deepseek_share, no_compile_cache):
+    """The 64 x 2,560 decode step and the 64 x 2,048 prefill by blocks
+    hand the grouped products each expert layer's held experts where the
+    layer stack keeps them: no array of one layer's held experts' shape
+    ([16, 5120, 1536] or [16, 1536, 5120]) appears in either optimized
+    program, and the decode step needs no more temporaries than with the
+    per-layer copies."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, model, params, place = deepseek_share
+    held, D, F = cfg.moe.held[1], cfg.d_model, cfg.moe.d_ff_expert
+    one_layer = [f"[{held},{D},{F}]", f"[{held},{F},{D}]"]
+    stack = params["groups"][1]["moe"]["wi"].shape
+    assert stack == (cfg.n_layers - cfg.n_dense_layers, held, D, F)
+
+    B, L = 64, 2560
+    caches = place(jax.eval_shape(lambda: model.init_cache(B, L)))
+    step = place({"token": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                  "pos": jax.ShapeDtypeStruct((), jnp.int32)})
+    decode = jax.jit(model.decode, donate_argnums=(1,)).lower(
+        params, caches, step).compile()
+    tok = place(jax.ShapeDtypeStruct((64, 2048), jnp.int32))
+    prefill = jax.jit(model.prefill).lower(params, {"tokens": tok}).compile()
+    for what, compiled in (("decode", decode), ("prefill", prefill)):
+        hlo = compiled.as_text()
+        assert "ragged" in hlo, what
+        found = [s for s in one_layer if s in hlo]
+        assert found == [], (what, found)
+    temp = decode.memory_analysis().temp_size_in_bytes
+    print(f"deepseek-v2 decode {B}x{L}: temp {temp} B (with the copies "
+          f"{DEEPSEEK_DECODE_TEMP_WITH_COPIES} B); prefill 64x2048: temp "
+          f"{prefill.memory_analysis().temp_size_in_bytes} B")
+    assert temp <= DEEPSEEK_DECODE_TEMP_WITH_COPIES
+
+
 # temp bytes of qwen3-4b's programs at the benchmark cells' shapes, as
 # compiled before the MLA and expert layers changed the shared attention
 # and decoder code; the qwen3-4b programs must not move

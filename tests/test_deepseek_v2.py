@@ -226,6 +226,51 @@ def test_dropless_passes_are_differentiable(monkeypatch):
         assert rel(a, b) < TOL
 
 
+@pytest.mark.parametrize("inputs", ["decode-64-rows", "prefill-two-passes",
+                                    "no-held-rows"])
+@pytest.mark.parametrize("layer", ["first", "middle", "last"])
+def test_expert_layer_reads_a_stacked_layer_in_place(monkeypatch, layer,
+                                                     inputs):
+    """The expert layer fed ``StackedLayer`` views of layer i of stacked
+    held experts (as the decoder's inference scans hand them) gives what
+    it gives fed layer i's own weights: a 64-row decode step, a prefill
+    block in passes of 8 routed rows, and a layer whose held experts
+    receive no row."""
+    cfg = small(2, 2)
+    n = 5
+    i = {"first": 0, "middle": n // 2, "last": n - 1}[layer]
+    stacked = jax.vmap(lambda k: layers.init_moe(k, cfg, jnp.float32))(
+        jax.random.split(jax.random.PRNGKey(SEED), n))
+    one = jax.tree.map(lambda a: a[i], stacked)
+    if inputs == "decode-64-rows":
+        x = jax.random.normal(jax.random.PRNGKey(3), (64, 1, cfg.d_model))
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(4), (2, 20, cfg.d_model))
+    if inputs == "prefill-two-passes":
+        monkeypatch.setattr(layers, "MOE_ROWS", 8)
+    if inputs == "no-held-rows":
+        # every logit of the held experts (2, 3) below every other's
+        first, held = cfg.moe.held
+        r = jnp.abs(one["router"])
+        one["router"] = r.at[:, first:first + held].multiply(-1.0)
+        x = jnp.abs(x)
+    views = dict(one, **{k: layers.StackedLayer(stacked[k], jnp.int32(i))
+                         for k in ("wi", "wg", "wo")})
+    ffn = jax.jit(lambda p, x: layers.moe_ffn(p, cfg, x))
+    want, load = ffn(one, x)
+    got, got_load = ffn(views, x)
+    np.testing.assert_array_equal(got_load, load)
+    assert rel(got, want) < TOL
+    routed = x.shape[0] * x.shape[1] * cfg.moe.top_k
+    if inputs == "no-held-rows":
+        assert int(load[0]) == 0
+        shared = common.mlp(one["shared"], x, cfg.act)
+        assert rel(got, shared) < TOL
+    else:
+        assert int(load[0]) > (8 if inputs == "prefill-two-passes" else 0)
+        assert int(load[0]) < routed
+
+
 def test_prefill_by_blocks_of_rows_is_the_whole_batchs():
     """``prefill_tokens`` runs the batch 2 rows at a time inside one
     program: the same logits and caches, and the blocks' expert counts
