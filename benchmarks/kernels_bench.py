@@ -13,7 +13,6 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_routing import moe_routing
 from repro.kernels.rwkv_scan import rwkv_scan
-from repro.kernels.scheduler_score import scheduler_score
 
 
 def timeit(fn, *args, n=5):
@@ -61,14 +60,3 @@ def run(emit=print):
     emit(f"kernel,rwkv_scan,us_per_call={timeit(rs, r_, r_, r_, w_, u_):.0f},"
          f"ref_us={timeit(rr, r_, r_, r_, w_, u_):.0f}")
 
-    import numpy as np
-    rng = np.random.default_rng(0)
-    qps = jnp.asarray(rng.uniform(0.1, 100, (512, 16)), jnp.float32)
-    pre = jnp.asarray(rng.uniform(0, 5, (512, 16)), jnp.float32)
-    qq = jnp.asarray(rng.integers(1, 1000, 512), jnp.float32)
-    rem = jnp.asarray(rng.uniform(1, 500, 512), jnp.float32)
-    ss = jax.jit(lambda a, b, c, d: scheduler_score(a, b, c, d,
-                                                    interpret=True))
-    emit(f"kernel,scheduler_score,"
-         f"us_per_call={timeit(ss, qps, pre, qq, rem):.0f},"
-         f"jobs=512,workers=16")

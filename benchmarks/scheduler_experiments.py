@@ -5,8 +5,6 @@ fleet-scale benches:
 * ``bench_fleet``   — 10k-job x 64-pool MMPP scenario under every policy on
   the event-heap engine, and the old-vs-new simulator wall-clock
   head-to-head (seed tick-scanning loop vs indexed event heap).
-* ``bench_scoring`` — numpy ``estimate_matrix`` vs the Pallas
-  ``scheduler_score`` kernel at J~2048 x W=256.
 * ``bench_serving`` — job-level vs batched (serving bridge) QoS and
   wall-clock on an overloaded MMPP fleet scenario: the same trace served
   with exclusive job occupancy vs continuous batching
@@ -20,10 +18,10 @@ fleet-scale benches:
 * ``bench_sched_overhead`` — per-tick scheduler *decision* wall-clock
   under a standing MMPP backlog with queue churn: the PR4 uncached
   full-matrix path vs the incremental score-cache path
-  (``SynergAI(incremental=False)`` vs default) vs the Pallas backends
-  (v1 kernel, fused v2), sweeping J up to 50k jobs and W up to 256
-  pools.  Writes ``BENCH_SCHED.json`` (``--sched-json``) — the committed
-  copy at the repo root is the perf-trajectory baseline that
+  (``SynergAI(incremental=False)`` vs default) vs the device-resident
+  tick (``SynergAI(device=True)``), sweeping J up to 50k jobs and W up
+  to 256 pools.  Writes ``BENCH_SCHED.json`` (``--sched-json``) — the
+  committed copy at the repo root is the perf-trajectory baseline that
   ``tools/check_perf_regression.py`` gates nightly CI against.
 * ``bench_traces`` — the trace-driven scenario subsystem: every policy on
   (a) a *replayed* mmpp overload trace (exported with ``save_trace``,
@@ -41,7 +39,7 @@ fleet-scale benches:
   (``overload_headline``, gated nightly).
 
 Run standalone:  PYTHONPATH=src python benchmarks/scheduler_experiments.py
-(see --help for the fleet/scoring/serving knobs; ``--json`` dumps the
+(see --help for the fleet/serving knobs; ``--json`` dumps the
 fleet/serving/streaming bench outputs for CI artifacts)
 """
 
@@ -164,45 +162,6 @@ def bench_fleet(cd=None, n_jobs=10_000, pools=(8, 28, 28),
                    / max(walls[(P.name, "event-heap")][0], 1e-9))
         emit(f"simulator_headline,{P.name},"
              f"event_heap_speedup={speedup:.2f}x")
-    return walls
-
-
-def bench_scoring(cd=None, J=2048, pools=(86, 85, 85), iters=5, emit=print):
-    """numpy estimate_matrix vs the Pallas scheduler_score kernel on a
-    fleet-scale queue (J x 256)."""
-    from repro.core.estimator import estimate_matrix
-    from repro.core.pallas_scoring import make_pallas_score_fn
-    from repro.core.workers import synth_fleet
-    from repro.core.workload import scenario
-
-    cd = cd or characterize()
-    fleet = synth_fleet(*pools)
-    workers = [w.name for w in fleet]
-    jobs = scenario(cd, "multi-tenant", n_jobs=J, fleet=fleet,
-                    seed=0)
-    now = jobs[-1].arrival  # everything queued
-    pallas_fn = make_pallas_score_fn()
-    s_np = s_pl = None
-    walls = {}
-    for label, fn in (("numpy", estimate_matrix), ("pallas", pallas_fn)):
-        fn(cd, jobs, workers, now)       # warm caches / tracing
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(cd, jobs, workers, now)
-        walls[label] = (time.perf_counter() - t0) / iters
-        if label == "numpy":
-            s_np = out
-        else:
-            s_pl = out
-        emit(f"scoring,{label},J={len(jobs)},W={len(workers)},"
-             f"wall_ms={walls[label] * 1e3:.2f}")
-    agree = int((s_np.best_worker == s_pl.best_worker).sum())
-    # interpret mode emulates the TPU kernel op-by-op on CPU — the point
-    # here is bit-level agreement and the [J, W] shape, not speed; compiled
-    # TPU numbers come from benchmarks/kernels_bench.py on real hardware
-    emit(f"scoring_headline,pallas_interpret_vs_numpy="
-         f"{walls['numpy'] / max(walls['pallas'], 1e-9):.2f}x,"
-         f"best_worker_agree={agree}/{len(jobs)}")
     return walls
 
 
@@ -345,8 +304,7 @@ def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
                                          (10_000, (8, 28, 28))),
                          iters=40, churn=64, tick=1.0,
                          pallas_max_j=2_000, utilization=0.8,
-                         only=None, interpret=None, strict_parity=False,
-                         emit=print):
+                         only=None, strict_parity=False, emit=print):
     """Per-tick scheduler decision time under a standing MMPP backlog.
 
     ``backlog_churn`` keeps the queue depth at ~J while churning it
@@ -355,39 +313,28 @@ def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
     variant rebuilds the full [J, W] matrix every tick, and the
     device-resident ``pallas-resident`` variant ships only the churn's
     rows host->device and runs the whole decision as one fused dispatch.
-    Pallas variants run compiled on a TPU backend and interpreted
-    elsewhere (``interpret=None``; on CPU the kernel is emulated
-    op-by-op — wall-clock is not the point there), capped at
-    ``pallas_max_j``; compiled variants carry a ``-compiled`` suffix so
-    their numbers land under their own regression keys.  The times are
-    host wall-clock.
+    That variant runs compiled on a TPU backend and interpreted elsewhere
+    (on CPU the kernel is emulated op-by-op — wall-clock is not the point
+    there), capped at ``pallas_max_j``; a compiled run carries a
+    ``-compiled`` suffix (read from ``pol.cache.interpret``) so its
+    numbers land under their own regression keys.  The times are host
+    wall-clock.
 
-    Every Pallas variant's per-tick assignments are compared against the
+    The device variant's per-tick assignments are compared against the
     cached numpy variant's on the identical churn stream and recorded as
     ``assignments_match_cached``; ``strict_parity=True`` raises on any
     mismatch (the CI smoke contract).  ``only`` restricts the run to
     ``("cached", only)`` for the tier-1 smoke leg."""
-    from repro.core.pallas_scoring import make_pallas_score_fn
     from repro.core.workers import synth_fleet
-    from repro.kernels.ops import resolve_interpret
 
     cd = cd or characterize()
-    interpret = resolve_interpret(interpret)
-    suffix = "" if interpret else "-compiled"
     variants = [
         ("uncached", lambda: SynergAI(incremental=False)),
         ("cached", lambda: SynergAI()),
-        ("pallas" + suffix, lambda: SynergAI(
-            score_fn=make_pallas_score_fn(interpret=interpret))),
-        ("pallas-v2" + suffix, lambda: SynergAI(
-            score_fn=make_pallas_score_fn(v2=True, interpret=interpret))),
-        ("pallas-resident" + suffix, lambda: SynergAI(
-            score_fn=make_pallas_score_fn(device_cache=True,
-                                          interpret=interpret))),
+        ("pallas-resident", lambda: SynergAI(device=True)),
     ]
     if only is not None:
-        keep = {"cached", only, only + suffix}
-        variants = [v for v in variants if v[0] in keep]
+        variants = [v for v in variants if v[0] in ("cached", only)]
     results = []
     for J, pools in sizes:
         W = len(synth_fleet(*pools))
@@ -399,6 +346,8 @@ def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
             asg_log, ticks, pol = backlog_churn(
                 cd, mk, J, pools, iters=iters, churn=churn, tick=tick,
                 utilization=utilization)
+            if pol.device and not pol.cache.interpret:
+                name += "-compiled"
             placed_total = sum(len({jid for jid, _ in a})
                                for a in asg_log)
             mean_ms = 1e3 * float(np.mean(ticks))
@@ -413,7 +362,7 @@ def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
                 rec["speedup_vs_uncached"] = base[(J, W)] / mean_ms
             if name == "cached":
                 cached_log = asg_log
-            if name.startswith("pallas") and cached_log is not None:
+            if pol.device and cached_log is not None:
                 match = asg_log == cached_log
                 rec["assignments_match_cached"] = match
                 if strict_parity and not match:
@@ -423,7 +372,7 @@ def bench_sched_overhead(cd=None, sizes=((2_000, (8, 28, 28)),
                     raise RuntimeError(
                         f"{name} diverged from cached at tick {bad}: "
                         f"{asg_log[bad][:4]} != {cached_log[bad][:4]}")
-            if name.startswith("pallas-resident"):
+            if pol.device:
                 dc = pol.cache
                 rec["hd_bytes_per_tick"] = dc.bytes_to_device / dc.ticks
                 rec["rows_uploaded"] = dc.rows_uploaded
@@ -991,7 +940,6 @@ def main(argv=None):
                         "flash | multi-tenant")
     p.add_argument("--skip-paper", action="store_true",
                    help="skip the 24-job paper experiments")
-    p.add_argument("--skip-scoring", action="store_true")
     p.add_argument("--skip-serving", action="store_true",
                    help="skip the job-level vs batched serving-bridge "
                         "comparison (scenario(..., serving='batched'))")
@@ -1015,14 +963,6 @@ def main(argv=None):
                         "against the cached numpy path — seconds; the "
                         "tier-1 CI sanity leg; exits nonzero on any "
                         "assignment divergence")
-    p.add_argument("--sched-backend", choices=("auto", "interpret",
-                                               "compiled"),
-                   default="auto",
-                   help="Pallas execution backend for the sched bench: "
-                        "auto (compiled on TPU, interpret elsewhere), "
-                        "interpret (CPU-emulated), compiled (lowered "
-                        "kernels; variants recorded under '-compiled' "
-                        "keys)")
     p.add_argument("--sched-json", metavar="PATH", default=None,
                    help="write the bench_sched_overhead + bench_regions "
                         "results as JSON (the BENCH_SCHED.json schema; "
@@ -1063,26 +1003,19 @@ def main(argv=None):
     if not args.skip_paper:
         print("# paper experiments (Fig. 7-10)")
         run(cd, seeds=(1, 2, 3))
-    if not args.skip_scoring:
-        print("# scoring: numpy vs Pallas kernel")
-        bench_scoring(cd)
-    interpret = (None if args.sched_backend == "auto"
-                 else args.sched_backend == "interpret")
     sched = None
     if args.sched_smoke:
         print(f"# scheduler overhead smoke: cached vs {args.sched_smoke}"
               " (strict parity)")
         sched = bench_sched_overhead(
             cd, sizes=((256, (2, 3, 3)),), iters=6, churn=16,
-            only=args.sched_smoke, interpret=interpret,
-            strict_parity=True)
+            only=args.sched_smoke, strict_parity=True)
     elif not args.skip_sched:
-        print("# scheduler overhead: uncached vs score-cache vs Pallas")
+        print("# scheduler overhead: uncached vs score-cache vs device")
         sizes = [(2_000, (8, 28, 28)), (10_000, (8, 28, 28))]
         if args.sched_big:
             sizes.append((50_000, (86, 85, 85)))
-        sched = bench_sched_overhead(cd, sizes=tuple(sizes),
-                                     interpret=interpret)
+        sched = bench_sched_overhead(cd, sizes=tuple(sizes))
     if not args.skip_regions:
         print("# region sharding: flat vs hierarchical scheduler")
         reg = bench_regions(cd, smoke=args.regions_smoke)

@@ -52,15 +52,13 @@ def phase_tick(cd, J=10_000, pools=(8, 28, 28), iters=40, churn=64,
                on_chip=True, emit=print):
     """Phase 1: device-resident tick vs cached numpy, tick by tick."""
     from benchmarks.scheduler_experiments import backlog_churn
-    from repro.core.pallas_scoring import make_pallas_score_fn
     from repro.core.scheduler import SynergAI
     from repro.core.workers import synth_fleet
 
     want, _, _ = backlog_churn(cd, SynergAI, J, pools, iters=iters,
                                churn=churn)
-    got, _, pol = backlog_churn(
-        cd, lambda: SynergAI(score_fn=make_pallas_score_fn(
-            device_cache=True)), J, pools, iters=iters, churn=churn)
+    got, _, pol = backlog_churn(cd, lambda: SynergAI(device=True), J,
+                                pools, iters=iters, churn=churn)
     _check_compiled(pol, on_chip)
     for i, (g, w) in enumerate(zip(got, want)):
         if g != w:
@@ -87,7 +85,6 @@ def _result_key(results):
 def phase_sim(cd, n_jobs=2_000, pools=(8, 28, 28), utilization=3.0,
               on_chip=True, emit=print):
     """Phase 2: a batched, streaming simulator run, device vs numpy."""
-    from repro.core.pallas_scoring import make_pallas_score_fn
     from repro.core.scheduler import SynergAI
     from repro.core.simulator import Simulator
     from repro.core.workers import synth_fleet
@@ -103,7 +100,7 @@ def phase_sim(cd, n_jobs=2_000, pools=(8, 28, 28), utilization=3.0,
                                      serving="batched").run(jobs))
 
     want = run(SynergAI())
-    pol = SynergAI(score_fn=make_pallas_score_fn(device_cache=True))
+    pol = SynergAI(device=True)
     got = run(pol)
     _check_compiled(pol, on_chip)
     if len(got) != len(want) or len(got) != n_jobs:

@@ -1,10 +1,11 @@
 """Fleet-scale scenario demo: a 64-pool synthetic cluster serving bursty,
 diurnal and multi-tenant traffic with worker failures, scheduled by
-SynergAI on the event-heap simulator — optionally scored by the Pallas
-kernel, optionally served through the continuous-batching serving bridge
-(--serving batched; see docs/serving_bridge.md).
+SynergAI on the event-heap simulator — optionally with each tick run by
+the device-resident Pallas kernel (--device), optionally served through
+the continuous-batching serving bridge (--serving batched; see
+docs/serving_bridge.md).
 
-    PYTHONPATH=src python examples/fleet_scale.py [--jobs 2000] [--pallas]
+    PYTHONPATH=src python examples/fleet_scale.py [--jobs 2000] [--device]
     PYTHONPATH=src python examples/fleet_scale.py --serving batched
 """
 
@@ -32,10 +33,11 @@ parser.add_argument("--serving", choices=("job", "batched"),
 parser.add_argument("--max-batch", type=int, default=8,
                     help="continuous-batch slot budget per worker "
                          "(batched serving only)")
-parser.add_argument("--pallas", action="store_true",
-                    help="score with the Pallas kernel; interpret mode "
-                         "emulates the TPU op-by-op on CPU, so keep "
-                         "--jobs <= ~100 off-accelerator")
+parser.add_argument("--device", action="store_true",
+                    help="run each scheduling tick as one device "
+                         "dispatch (SynergAI(device=True)); off a TPU the "
+                         "Pallas interpreter emulates it op-by-op, so "
+                         "keep --jobs <= ~100 there")
 args = parser.parse_args()
 
 cd = characterize()
@@ -43,11 +45,6 @@ fleet = synth_fleet(*args.pools)
 print(f"fleet: {len(fleet)} pools "
       f"(cloud={args.pools[0]}, edge-large={args.pools[1]}, "
       f"edge-small={args.pools[2]})")
-
-score_fn = None
-if args.pallas:
-    from repro.core.pallas_scoring import make_pallas_score_fn
-    score_fn = make_pallas_score_fn()
 
 for kind in SCENARIOS:
     jobs = scenario(cd, kind, n_jobs=args.jobs, fleet=fleet,
@@ -57,7 +54,7 @@ for kind in SCENARIOS:
     failures = synth_failures(fleet, span, mtbf_s=2 * span, mttr_s=120.0,
                               seed=0)
     t0 = time.perf_counter()
-    res = Simulator(cd, SynergAI(score_fn=score_fn), fleet=fleet,
+    res = Simulator(cd, SynergAI(device=args.device), fleet=fleet,
                     failures=failures, seed=0, serving=args.serving,
                     max_batch=args.max_batch).run(jobs)
     dt = time.perf_counter() - t0

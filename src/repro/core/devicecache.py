@@ -1,13 +1,11 @@
 """Device-resident mirror of the cross-tick score cache
 (docs/performance.md, "Device-resident scoring").
 
-``ScoreCache`` already makes the numpy hot path sublinear per tick; the
-Pallas backends, however, re-shipped the full ``[J, W]`` matrices from
-host to device on *every* tick, which is why ``BENCH_SCHED.json``'s
-``pallas``/``pallas-v2`` variants trail the cached numpy path by two
-orders of magnitude — the PerLLM (arXiv:2405.14636) per-decision-
-overhead argument, lost at the host/device boundary instead of in the
-scoring math.
+``ScoreCache`` already makes the numpy hot path sublinear per tick; a
+device scorer that re-shipped the full ``[J, W]`` matrices from host to
+device on every tick would lose that at the host/device boundary
+instead of in the scoring math (the PerLLM, arXiv:2405.14636,
+per-decision-overhead argument).
 
 ``DeviceScoreCache`` keeps the host ``ScoreCache`` as the row oracle
 (row *values* are host computations over the Configuration Dictionary)
@@ -53,9 +51,10 @@ import jax.numpy as jnp
 
 from repro.core.estimator import profile_gen
 from repro.core.scorecache import ScoreCache
-from repro.kernels.ops import resolve_interpret
+from repro.kernels.scheduler_score import resolve_interpret, scheduler_tick
 
 _COL_BLOCK = 128      # worker-axis padding block (f32 TPU lane width)
+_J_BLOCK = 128        # tick-kernel job block (rows per grid step)
 _ROW_BLOCK = 256      # slot-pool row padding block (matches _GROW)
 _UP_BLOCK = 8         # upload-batch padding block (recompile guard)
 
@@ -85,15 +84,14 @@ class DeviceScoreCache(ScoreCache):
     """A ``ScoreCache`` whose Eq. 2 rows are additionally resident on
     the jax device, plus the fused one-dispatch tick entry point."""
 
-    def __init__(self, use_default: bool = False, profile: int = 0,
-                 bj: int = 128, interpret=None):
+    def __init__(self, use_default: bool = False, profile: int = 0):
         # device pools (created lazily on first upload)
         self._dt = self._dpre = self._ddec = self._dene = None
         self._d_cap = 0
         self._d_Wp = 0
         super().__init__(use_default, profile)
-        self.bj = int(bj)
-        self.interpret = resolve_interpret(interpret)
+        # compiled tick on a TPU backend, the Pallas interpreter elsewhere
+        self.interpret = resolve_interpret()
         # transfer accounting (tests assert the O(churn * W) bound)
         self.fail_masks = 0          # fail_gen bumps absorbed by masking
         self.rows_uploaded = 0       # matrix rows shipped host -> device
@@ -259,16 +257,13 @@ class DeviceScoreCache(ScoreCache):
         """Run one whole scheduling decision on-device.  All inputs are
         host vectors over the live queue ([J]) or the fleet ([W] /
         [K, W]); Eq. 1 decay (t_rem, ttft_rem) is computed on host in
-        float64 from the cached scalars — an O(J) vector op whose f32
-        cast matches the fused v2 contract bit-for-bit.  Returns
-        (assign [Jp], order [Jp]) as numpy int32."""
-        from repro.kernels.scheduler_score import scheduler_tick
-
+        float64 from the cached scalars — an O(J) vector op, cast to f32
+        for the kernel.  Returns (assign [Jp], order [Jp]) as numpy
+        int32."""
         self._ensure_pools()
         J, W = len(slots), self._W
         Wp = self._d_Wp
-        bj = self.bj
-        Jp = _bucket(max(J, 1), bj)
+        Jp = _bucket(max(J, 1), _J_BLOCK)
         use_energy = escale is not None
 
         def padj(a, fill, dt):
@@ -310,6 +305,6 @@ class DeviceScoreCache(ScoreCache):
         assign, order = scheduler_tick(
             self._dt, pool_pre, pool_dec, pool_ene,
             *(jnp.asarray(a) for a in args),
-            use_energy=use_energy, bj=min(bj, Jp),
+            use_energy=use_energy, bj=_J_BLOCK,
             interpret=self.interpret)
         return np.asarray(assign), np.asarray(order)

@@ -1,9 +1,10 @@
 """Execution Time Estimator + QoS Violation Detection (paper Eq. 1-4),
 vectorized over the (jobs x workers) matrix.
 
-The numpy path is authoritative; ``repro.kernels.scheduler_score`` is the
-TPU Pallas version of the same scoring used at fleet scale (J, W large), and
-is validated against this module in the kernel tests.
+The numpy path is authoritative; ``scheduler_tick``
+(``repro.kernels.scheduler_score``) is the TPU Pallas version of the same
+decision used at fleet scale (J, W large), and is validated against it in
+the tests.
 """
 
 from __future__ import annotations
@@ -347,7 +348,7 @@ def score_matrices(cd: ConfigDict, jobs: Sequence[Job], workers: List[str],
     """[J, W] qps / preproc matrices from the Configuration Dictionary
     (``qps == 0`` marks infeasible pairs), cached per worker tuple on the
     ConfigDict.  Shared input builder for the numpy scorer below and the
-    Pallas kernel path (``repro.core.pallas_scoring``)."""
+    score cache's rows (``repro.core.scorecache``)."""
     return _table(cd, workers, use_default, token, profile).gather(jobs)[:2]
 
 
@@ -420,13 +421,6 @@ def estimate_matrix(cd: ConfigDict, jobs: Sequence[Job], workers: List[str],
         doomed = ~acceptable.any(axis=1)
         return ScoreResult(workers, t_est, t_rem, acceptable,
                            best.astype(np.int64), urgency, doomed)
-
-
-# score_fn protocol markers: SynergAI forwards the cluster's interned
-# worker token — and, when a recharacterizer is attached, the profile
-# overlay id — to backends that advertise support for them
-estimate_matrix.takes_token = True
-estimate_matrix.takes_profile = True
 
 
 def candidate_order(score: ScoreResult, ji: int,

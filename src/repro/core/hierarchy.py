@@ -334,11 +334,11 @@ class HierarchicalSynergAI(Policy):
     name = "SynergAI-H"
     use_default_config = False
 
-    def __init__(self, score_fn=None, incremental: bool = True,
+    def __init__(self, device: bool = False, incremental: bool = True,
                  spill: bool = True, recharacterizer=None,
                  energy_weight: float = 0.0, carbon=None,
                  overload=None):
-        self._score_fn = score_fn
+        self._device = device
         self._incremental = incremental
         self.spill = spill
         # one shared OverloadController consulted by every per-region
@@ -374,7 +374,7 @@ class HierarchicalSynergAI(Policy):
         sub = self._subs.get(region)
         if sub is None:
             sub = self._subs[region] = SynergAI(
-                score_fn=self._score_fn, incremental=self._incremental,
+                device=self._device, incremental=self._incremental,
                 recharacterizer=self.recharacterizer,
                 energy_weight=self.energy_weight, carbon=self.carbon,
                 overload=self.overload)

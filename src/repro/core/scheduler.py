@@ -28,12 +28,11 @@ The placement pass is fully vectorized for fleet scale (thousands of queued
 jobs x hundreds of pools): per-job candidate walks become masked argmins
 over a shared cost matrix — provably the same assignment as walking the
 stable-sorted candidate list, since ``argmin`` breaks ties at the lowest
-worker index exactly like a stable sort does.  ``score_fn`` swaps the
-scoring backend: the numpy estimator by default, the Eq. 2-4 Pallas kernel
-via ``repro.core.pallas_scoring.make_pallas_score_fn()``, or the fused v2
-kernel (``make_pallas_score_fn(v2=True)``) that additionally folds the
-batched depth penalty, the prefill/decode phase split and the TTFT/TPOT
-streaming gates into one on-accelerator pass.
+worker index exactly like a stable sort does.  ``SynergAI(device=True)``
+runs the same decision on the accelerator instead: the cache's rows live
+in device pools (``repro.core.devicecache.DeviceScoreCache``) and each
+tick is one ``repro.kernels.scheduler_score.scheduler_tick`` dispatch,
+bit-for-bit the cached numpy schedule (``tests/test_devicecache.py``).
 
 Under the batched serving bridge (``Simulator(..., serving="batched")``)
 the estimates become *queue-depth-aware*: every worker's column is scaled
@@ -71,12 +70,14 @@ class SynergAI(Policy):
     name = "SynergAI"
     use_default_config = False
 
-    def __init__(self, score_fn=None, incremental: bool = True,
+    def __init__(self, device: bool = False, incremental: bool = True,
                  recharacterizer=None, energy_weight: float = 0.0,
                  carbon=None, overload=None):
-        # score_fn: optional accelerated scorer — the Eq. 2-4 Pallas
-        # kernel, or the fused v2 kernel (``fused`` attribute) which also
-        # consumes the depth penalty / phase split / streaming gates.
+        # device: run every tick on the accelerator — the score cache's
+        # rows stay in device pools and the whole decision is one
+        # ``scheduler_tick`` dispatch (compiled on a TPU backend, the
+        # Pallas interpreter elsewhere); the schedule is the cached
+        # numpy path's.
         # incremental=False disables the cross-tick score cache (the
         # uncached reference path, e.g. for the perf bench baseline).
         # recharacterizer: an ``OnlineRecharacterizer`` closing the
@@ -105,35 +106,18 @@ class SynergAI(Policy):
         self.overload = overload
         self._regions_key = None
         self._regions: tuple = ()
-        self.score_fn = score_fn or estimate_matrix
-        self._fused = bool(getattr(score_fn, "fused", False))
-        self._device = bool(getattr(score_fn, "device_cache", False))
-        self._takes_token = bool(getattr(self.score_fn, "takes_token",
-                                         False))
-        self._takes_profile = bool(getattr(self.score_fn, "takes_profile",
-                                           False))
+        self.device = bool(device)
         self.recharacterizer = recharacterizer
         self.profile = recharacterizer.profile if recharacterizer else 0
-        if (recharacterizer is not None and score_fn is not None
-                and not (self._fused or self._takes_profile)):
-            raise ValueError(
-                "recharacterizer needs a score_fn that reads the profile "
-                "overlay: the default numpy estimator, the fused v2 "
-                "kernel, or a backend advertising takes_profile")
-        # a conventional custom score_fn builds its own matrices, so the
-        # row cache would be dead weight; the fused kernel reads its
-        # matrices *from* the cache, so it always carries one; the
-        # device-resident backend carries the device-mirrored subclass
-        if self._device:
+        # the device path reads its rows from the device-mirrored cache
+        # subclass; the uncached reference path carries no cache
+        if self.device:
             from repro.core.devicecache import DeviceScoreCache
             self.cache: Optional[ScoreCache] = DeviceScoreCache(
-                profile=self.profile,
-                bj=getattr(score_fn, "bj", 128),
-                interpret=getattr(score_fn, "interpret", None))
+                profile=self.profile)
         else:
-            self.cache = (
-                ScoreCache(profile=self.profile) if self._fused
-                or (incremental and score_fn is None) else None)
+            self.cache = (ScoreCache(profile=self.profile) if incremental
+                          else None)
 
     # -- online re-characterization hooks (inert without one) ----------
 
@@ -192,14 +176,10 @@ class SynergAI(Policy):
         ew = self.energy_weight
         if ew:
             cache.ensure_energy_rows(cd, queue, slots, cluster)
-        if self._device:
+        if self.device:
             return self._schedule_device(now, queue, cluster, avail, slots,
                                          t_rem, pen, has_ttft, has_tpot,
                                          batched, disagg)
-        if self._fused:
-            return self._schedule_fused(now, queue, cluster, avail, slots,
-                                        t_rem, pen, has_ttft, has_tpot,
-                                        batched, disagg, streaming)
         if not (disagg or streaming):
             # the plain tick: every cached row is still exact, so only
             # Eq. 1's decay moves — urgency and doom are O(J) vector ops
@@ -445,47 +425,14 @@ class SynergAI(Policy):
         return out
 
     # ------------------------------------------------------------------
-    # fused Pallas path: depth penalty + phase split + streaming gates
-    # run inside the kernel; the cache supplies its input matrices
-
-    def _schedule_fused(self, now, queue, cluster, avail, slots, t_rem,
-                        pen, has_ttft, has_tpot, batched, disagg,
-                        streaming):
-        cache = self.cache
-        t0 = cache.t_matrix(slots)
-        if streaming or disagg:
-            pre_m, dec_m = cache.phase_matrices(slots)
-        else:
-            pre_m = dec_m = t0      # gates are off: placeholders
-        phase = np.zeros(len(queue), dtype=np.int8)
-        if disagg:
-            phase = np.fromiter(
-                (PHASE_CODE[cluster.phase_of(j)] for j in queue),
-                dtype=np.int8, count=len(queue))
-        ttft_rem = cache.ttft_qos(slots) - cache.waiting(slots, now)
-        t, acceptable, urgency, doomed = self.score_fn(
-            t0, pre_m, dec_m, t_rem, pen, phase, has_ttft, has_tpot,
-            ttft_rem, cache.tpot_qos(slots), cache.dtok(slots))
-        shed = (self.overload.consult(now, queue, doomed, urgency)
-                if self.overload is not None else None)
-        return self._place(now, queue, cluster, avail, t, acceptable,
-                           urgency, doomed, batched, phase,
-                           self._energy_cost(cache, slots, cluster, now)
-                           if self.energy_weight else None, skip=shed)
-
-    # ------------------------------------------------------------------
-    # reference path: full [J, W] rebuild every tick (incremental=False,
-    # or a conventional custom score_fn)
+    # reference path: full [J, W] rebuild every tick (incremental=False)
 
     def _schedule_full(self, now, queue, cluster, avail):
         workers = cluster.arrays.names
-        kw = {}
-        if self._takes_token:
-            kw["token"] = cluster.worker_token
-        if self._takes_profile and self.profile:
-            kw["profile"] = self.profile
-        score = self.score_fn(cluster.cd, queue, workers, now,
-                              use_default=False, **kw)
+        score = estimate_matrix(cluster.cd, queue, workers, now,
+                                use_default=False,
+                                token=cluster.worker_token,
+                                profile=self.profile)
         t = score.t_estimated
         doomed = score.doomed
         acceptable = score.acceptable

@@ -13,15 +13,8 @@ from functools import partial
 import jax
 
 from repro.kernels import (decode_attention as _da, flash_attention as _fa,
-                           moe_routing as _mr, rwkv_scan as _rs,
-                           scheduler_score as _ss)
-
-
-def resolve_interpret(interpret=None) -> bool:
-    """``None`` -> interpret everywhere but on a TPU backend."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+                           moe_routing as _mr, rwkv_scan as _rs)
+from repro.kernels.scheduler_score import resolve_interpret
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
@@ -51,10 +44,3 @@ def rwkv_scan(r, k, v, w, u, *, chunk=64, interpret=None):
     interpret = resolve_interpret(interpret)
     return _rs.rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=interpret)
 
-
-@partial(jax.jit, static_argnames=("bj", "interpret"))
-def scheduler_score(qps, preproc, queries, t_remaining, *, bj=128,
-                    interpret=None):
-    interpret = resolve_interpret(interpret)
-    return _ss.scheduler_score(qps, preproc, queries, t_remaining, bj=bj,
-                               interpret=interpret)

@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=None, k_valid=None):
@@ -62,19 +61,3 @@ def rwkv_scan_ref(r, k, v, w, u):
     _, outs = jax.lax.scan(step, jnp.zeros((B, H, hd, hd), jnp.float32), xs)
     return outs.transpose(1, 0, 2, 3).astype(r.dtype)
 
-
-def scheduler_score_ref(qps, preproc, queries, t_remaining):
-    """Numpy oracle of Eq. 2-4 (mirrors core.estimator.estimate_matrix)."""
-    qps = np.asarray(qps, np.float32)
-    preproc = np.asarray(preproc, np.float32)
-    queries = np.asarray(queries, np.float32)
-    t_rem = np.asarray(t_remaining, np.float32)
-    feas = qps > 0
-    est = np.where(feas, preproc + queries[:, None] / np.where(feas, qps, 1),
-                   3.0e38).astype(np.float32)
-    acc = feas & (t_rem[:, None] >= est)
-    est_m = np.where(acc, est, 3.0e38)
-    best = np.where(acc.any(1), est_m.argmin(1),
-                    np.where(feas.any(1), est.argmin(1), -1))
-    urgency = t_rem - est.min(1)
-    return est, best.astype(np.int32), urgency, acc.astype(np.int8)

@@ -1,29 +1,25 @@
-"""SynergAI Eq. 2-4 scoring Pallas TPU kernels (v1 + fused v2).
+"""SynergAI's scheduling decision (paper Eq. 1-4) as one Pallas TPU
+dispatch: ``scheduler_tick``.
 
-At fleet scale (thousands of queued jobs x hundreds of worker pools) the
-scheduler's scoring step is itself a dense [J, W] compute:
+At fleet scale (thousands of queued jobs x hundreds of worker pools)
+the decision is a dense [J, W] compute followed by a greedy walk:
 
-    T_est[j, w]   = preproc[j, w] + q[j] / qps[j, w]          (Eq. 2)
-    acceptable    = T_rem[j] >= T_est[j, w]                   (Eq. 3)
-    best[j]       = argmin_w T_est[j, w] over acceptable      (Eq. 4)
-    urgency[j]    = T_rem[j] - min_w T_est[j, w]
+    t_eff[j, w]  = phase-sliced Eq. 2 estimate x queue-depth penalty
+    acceptable   = t_rem[j] >= t_eff[j, w], and the TTFT/TPOT gates  (Eq. 3)
+    urgency[j]   = t_rem[j] - min_w t_solo[j, w], TTFT-tightened
+    placement    = urgency-ordered masked argmin over open workers (Eq. 4)
 
-Grid walks J-blocks with the full worker axis resident in VMEM; infeasible
-(j, w) pairs carry qps <= 0 and are excluded via masking.  Validated against
-``repro.core.estimator.estimate_matrix`` (the numpy oracle) in the tests.
-
-``scheduler_score_v2`` fuses the whole batched-serving scoring pipeline —
-the queue-depth penalty (``1 + alpha * b`` per worker), the
-prefill/decode phase slicing of disaggregated pools, and the TTFT/TPOT
-streaming-deadline gates — into a single pass, so the Pallas path covers
-``serving="batched"`` + streaming scoring end-to-end instead of falling
-back to numpy post-processing.  It consumes the *precomputed* solo
-matrices (full service, prefill prefix, decode remainder, ``inf`` marking
-infeasible pairs — exactly what ``repro.core.scorecache`` persists across
-ticks) and emits the effective times, the gated acceptability, the
-TTFT-tightened urgency, and doom.  The numpy oracle is the batched +
-streaming block of ``repro.core.scheduler.SynergAI``; parity, including
-the padding edges, is pinned in ``tests/test_pallas_parity.py``."""
+The Eq. 2 rows stay on the device across ticks
+(``repro.core.devicecache.DeviceScoreCache``), so a steady tick ships
+only O(churn * W) row bytes plus O(J + W) per-tick vectors.
+``scheduler_tick`` gathers the live rows by slot index, runs the scoring
+kernel ``_tick_kernel`` over J-blocks with the worker axis resident in
+VMEM, and finishes the placement inside the same jit dispatch; the host
+gets back only the (job, worker) assignment indices.  The oracle is the
+cached numpy path of ``repro.core.scheduler.SynergAI``; parity, padding
+edges included, is pinned in ``tests/test_tick_parity.py``,
+``tests/test_devicecache.py`` and ``tests/test_kernels.py``.
+"""
 
 from __future__ import annotations
 
@@ -33,189 +29,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BIG = 3.0e38
 
-
-def _score_kernel(qps_ref, pre_ref, q_ref, rem_ref,
-                  est_ref, best_ref, urg_ref, acc_ref):
-    qps = qps_ref[...]                  # [BJ, W]
-    pre = pre_ref[...]
-    q = q_ref[...]                      # [BJ, 1]
-    rem = rem_ref[...]                  # [BJ, 1]
-
-    feas = qps > 0.0
-    est = jnp.where(feas, pre + q / jnp.where(feas, qps, 1.0), BIG)
-    acc = feas & (rem >= est)
-    est_masked = jnp.where(acc, est, BIG)
-    # argmin over acceptable; fall back to argmin over feasible
-    any_acc = jnp.any(acc, axis=1, keepdims=True)
-    pick_from = jnp.where(any_acc, est_masked, est)
-    best = jnp.argmin(pick_from, axis=1)
-    any_feas = jnp.any(feas, axis=1)
-    best = jnp.where(any_feas, best, -1)
-    urgency = rem[:, 0] - jnp.min(est, axis=1)
-
-    est_ref[...] = est
-    best_ref[...] = best.astype(jnp.int32)
-    urg_ref[...] = urgency
-    acc_ref[...] = acc.astype(jnp.int8)
-
-
-def scheduler_score(qps, preproc, queries, t_remaining, *, bj=128,
-                    interpret=False):
-    """qps, preproc: [J, W] f32 (qps <= 0 marks infeasible); queries,
-    t_remaining: [J] f32.  Returns (t_est [J,W], best [J], urgency [J],
-    acceptable [J,W] int8)."""
-    J, W = qps.shape
-    bj = min(bj, J)
-    pad = (-J) % bj
-    if pad:
-        z = lambda a, fill: jnp.pad(a, [(0, pad)] + [(0, 0)] *
-                                    (a.ndim - 1), constant_values=fill)
-        qps, preproc = z(qps, 0.0), z(preproc, 0.0)
-        queries, t_remaining = z(queries, 1.0), z(t_remaining, -1.0)
-        J = J + pad
-    out = pl.pallas_call(
-        _score_kernel,
-        grid=(J // bj,),
-        in_specs=[
-            pl.BlockSpec((bj, W), lambda i: (i, 0)),
-            pl.BlockSpec((bj, W), lambda i: (i, 0)),
-            pl.BlockSpec((bj, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bj, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bj, W), lambda i: (i, 0)),
-            pl.BlockSpec((bj,), lambda i: (i,)),
-            pl.BlockSpec((bj,), lambda i: (i,)),
-            pl.BlockSpec((bj, W), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((J, W), jnp.float32),
-            jax.ShapeDtypeStruct((J,), jnp.int32),
-            jax.ShapeDtypeStruct((J,), jnp.float32),
-            jax.ShapeDtypeStruct((J, W), jnp.int8),
-        ],
-        interpret=interpret,
-    )(qps.astype(jnp.float32), preproc.astype(jnp.float32),
-      queries.astype(jnp.float32)[:, None],
-      t_remaining.astype(jnp.float32)[:, None])
-    est, best, urg, acc = out
-    n = J - pad
-    return est[:n], best[:n], urg[:n], acc[:n]
-
-
-def _score_v2_kernel(t_ref, pre_ref, dec_ref, rem_ref, pen_ref, phase_ref,
-                     hft_ref, hpt_ref, trem_ref, tq_ref, dtok_ref,
-                     est_ref, acc_ref, urg_ref, doom_ref):
-    t = t_ref[...]                      # [BJ, W] solo full service (inf=infeasible)
-    pre = pre_ref[...]                  # [BJ, W] prefill prefix
-    dec = dec_ref[...]                  # [BJ, W] decode remainder
-    rem = rem_ref[...]                  # [BJ, 1] Eq. 1 remaining budget
-    pen = pen_ref[...]                  # [1, W] queue-depth penalty
-    phase = phase_ref[...]              # [BJ, 1] 0 full / 1 prefill / 2 decode
-    has_ttft = hft_ref[...] != 0        # [BJ, 1]
-    has_tpot = hpt_ref[...] != 0
-    ttft_rem = trem_ref[...]            # [BJ, 1] TTFT budget minus waiting
-    tpot_qos = tq_ref[...]              # [BJ, 1] (inf = no deadline)
-    dtok = dtok_ref[...]                # [BJ, 1] decoded tokens (inf = n/a)
-
-    # phase-sliced, depth-penalized effective service time
-    t_eff = jnp.where(phase == 1, pre, jnp.where(phase == 2, dec, t))
-    t_eff = t_eff * pen
-    acc = rem >= t_eff                                        # Eq. 3
-    # streaming gates on the penalized phase split: a decode-phase job's
-    # TTFT is history, a prefill-phase job's TPOT belongs to its decode
-    ttft_est = pre * pen
-    tpot_est = dec * pen / dtok
-    acc &= (~has_ttft) | (phase == 2) | (ttft_est <= ttft_rem)
-    acc &= (~has_tpot) | (phase == 1) | (tpot_est <= tpot_qos)
-    # urgency decays from the *solo* estimate (matching the numpy path);
-    # a scarce TTFT budget can become the binding urgency
-    urg = rem[:, 0] - jnp.min(t, axis=1)
-    ttft_slack = ttft_rem[:, 0] - jnp.min(ttft_est, axis=1)
-    urg = jnp.where(has_ttft[:, 0] & (phase[:, 0] != 2),
-                    jnp.minimum(urg, ttft_slack), urg)
-    doom = ~jnp.any(acc, axis=1)
-
-    est_ref[...] = t_eff
-    acc_ref[...] = acc.astype(jnp.int8)
-    urg_ref[...] = urg
-    doom_ref[...] = doom.astype(jnp.int8)
-
-
-def scheduler_score_v2(t_solo, prefill, decode, t_remaining, pen, phase,
-                       has_ttft, has_tpot, ttft_rem, tpot_qos, dtok, *,
-                       bj=128, interpret=False):
-    """Fused batched + streaming + disaggregated scoring.
-
-    t_solo, prefill, decode: [J, W] f32 solo-service matrices (``inf``
-    marks infeasible pairs); pen: [W] f32 depth penalty; t_remaining,
-    ttft_rem, tpot_qos, dtok: [J] f32; phase: [J] int (0/1/2); has_ttft,
-    has_tpot: [J] int (0/1).  Returns (t_eff [J,W], acceptable [J,W]
-    int8, urgency [J], doomed [J] int8)."""
-    J, W = t_solo.shape
-    bj = min(bj, J)
-    pad = (-J) % bj
-    if pad:
-        z = lambda a, fill: jnp.pad(a, [(0, pad)] + [(0, 0)] *
-                                    (a.ndim - 1), constant_values=fill)
-        inf = jnp.inf
-        t_solo, prefill, decode = (z(t_solo, inf), z(prefill, inf),
-                                   z(decode, inf))
-        t_remaining, ttft_rem = z(t_remaining, -1.0), z(ttft_rem, -1.0)
-        tpot_qos, dtok = z(tpot_qos, 1.0), z(dtok, 1.0)
-        phase, has_ttft, has_tpot = (z(phase, 0), z(has_ttft, 0),
-                                     z(has_tpot, 0))
-        J = J + pad
-    col = lambda a, dt: a.astype(dt)[:, None]
-    jw = pl.BlockSpec((bj, W), lambda i: (i, 0))
-    j1 = pl.BlockSpec((bj, 1), lambda i: (i, 0))
-    out = pl.pallas_call(
-        _score_v2_kernel,
-        grid=(J // bj,),
-        in_specs=[jw, jw, jw, j1,
-                  pl.BlockSpec((1, W), lambda i: (0, 0)),   # pen: resident
-                  j1, j1, j1, j1, j1, j1],
-        out_specs=[jw,
-                   jw,
-                   pl.BlockSpec((bj,), lambda i: (i,)),
-                   pl.BlockSpec((bj,), lambda i: (i,))],
-        out_shape=[
-            jax.ShapeDtypeStruct((J, W), jnp.float32),
-            jax.ShapeDtypeStruct((J, W), jnp.int8),
-            jax.ShapeDtypeStruct((J,), jnp.float32),
-            jax.ShapeDtypeStruct((J,), jnp.int8),
-        ],
-        interpret=interpret,
-    )(t_solo.astype(jnp.float32), prefill.astype(jnp.float32),
-      decode.astype(jnp.float32), col(t_remaining, jnp.float32),
-      pen.astype(jnp.float32)[None, :], col(phase, jnp.int32),
-      col(has_ttft, jnp.int32), col(has_tpot, jnp.int32),
-      col(ttft_rem, jnp.float32), col(tpot_qos, jnp.float32),
-      col(dtok, jnp.float32))
-    est, acc, urg, doom = out
-    n = J - pad
-    return est[:n], acc[:n], urg[:n], doom[:n]
-
-
-# ---------------------------------------------------------------------------
-# fused whole-tick kernel: scoring + placement in one device dispatch
-#
-# ``scheduler_tick`` is the device-resident path's entry point
-# (``repro.core.devicecache.DeviceScoreCache``): the Eq. 2 row pools stay
-# on-device across ticks, so a steady-state tick ships only O(churn * W)
-# row bytes plus O(J + W) per-tick vectors, gathers the live rows by slot
-# index on-device, runs the fused scoring kernel below, and finishes the
-# whole Eq. 4 placement (urgency-ordered greedy masked argmin over open
-# slots) inside the same jit dispatch — the host gets back just the
-# (job, worker) assignment indices.
+def resolve_interpret(interpret=None) -> bool:
+    """``None`` -> interpret everywhere but on a TPU backend (the lowered
+    Mosaic kernels there, the Pallas interpreter on any other, which is
+    how the tests run them on CPU).  A caller that must not fall back
+    checks the resolved value."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def _tick_kernel(t_ref, pre_ref, dec_ref, rem_ref, pen_ref, bw_ref,
                  phase_ref, hft_ref, hpt_ref, trem_ref, tq_ref, dtok_ref,
                  cost_ref, elig_ref, urg_ref, doom_ref):
-    """The v2 scoring recipe extended through the placement-cost prep of
+    """Eq. 2-4 scoring through the placement-cost prep of
     ``SynergAI._place``: emits the ranking cost (doomed rows carry the
     busy-wait completion cost), the eligibility mask (doomed rows use the
     1.5x-of-best gate over feasible workers, everything else the gated

@@ -11,10 +11,9 @@ The acceptance anchors of the device-residency PR:
   sanctioned divergence (a pure ``fail_gen`` bump *masks* on the device
   path instead of flushing, because failure state never enters the
   rows);
-* ``SynergAI(score_fn=make_pallas_score_fn(device_cache=True))`` is
-  bit-for-bit the cached numpy scheduler in interpret mode — the PR 2/4
-  golden digests reproduce in both serving modes, flat and
-  ``RegionView``-sliced hierarchical;
+* ``SynergAI(device=True)`` is bit-for-bit the cached numpy scheduler
+  in interpret mode — the PR 2/4 golden digests reproduce in both
+  serving modes, flat and ``RegionView``-sliced hierarchical;
 * steady-state host->device traffic is O(churn * W), not O(J * W).
 """
 
@@ -29,7 +28,6 @@ from repro.core.devicecache import DeviceScoreCache
 from repro.core.estimator import (new_profile_id, profile_overlay,
                                   profile_gen)
 from repro.core.hierarchy import HierarchicalSynergAI
-from repro.core.pallas_scoring import make_pallas_score_fn
 from repro.core.scheduler import SynergAI
 from repro.core.scorecache import ScoreCache
 from repro.core.simulator import Simulator
@@ -40,10 +38,6 @@ from test_streaming_qos import PR2_GOLDEN, STREAM_GOLDEN
 from test_trace_replay import REPLAY_GOLDEN_DIGEST
 
 _APPROX = 1e-9
-
-
-def _device_fn():
-    return make_pallas_score_fn(device_cache=True)
 
 
 def _result_key(results):
@@ -226,22 +220,42 @@ def test_profile_refresh_reships_only_touched_rows(configdict):
 # drop-in scheduling parity: device path == cached numpy path, bit-for-bit
 
 
-@pytest.mark.parametrize("serving,streaming,disaggregate",
-                         [("job", None, False),
-                          ("batched", None, False),
-                          ("batched", (2.0, 2.5), False),
-                          ("batched", (2.0, 2.5), True)])
-def test_device_drop_in_matches_numpy(configdict, serving, streaming,
-                                      disaggregate):
-    cd = configdict
+def _drop_in_case(cd, case):
+    """(jobs, Simulator keywords) of one drop-in scenario."""
+    if case == "paper-DH-FH":
+        from repro.core.job import make_experiment
+        return make_experiment(cd, "DH", "FH", seed=11), dict(seed=11)
+    if case == "fleet-disagg-streaming":
+        fleet = synth_fleet(2, 3, 3, disaggregate=True)
+        jobs = scenario(cd, "mmpp", n_jobs=120, fleet=fleet, seed=3,
+                        utilization=1.0, serving="batched",
+                        streaming=(2.0, 2.5))
+        return jobs, dict(fleet=fleet, seed=3, serving="batched")
+    serving, streaming, disaggregate = {
+        "job-None-False": ("job", None, False),
+        "batched-None-False": ("batched", None, False),
+        "batched-streaming2-False": ("batched", (2.0, 2.5), False),
+        "batched-streaming3-True": ("batched", (2.0, 2.5), True),
+    }[case]
     fleet = synth_fleet(1, 2, 2, disaggregate=disaggregate)
     jobs = scenario(cd, "mmpp", n_jobs=60, fleet=fleet, seed=7,
                     utilization=1.2, serving=serving,
                     streaming=streaming)
-    run = lambda pol: _result_key(
-        Simulator(cd, pol, fleet=fleet, seed=7, serving=serving)
-        .run(jobs))
-    assert run(SynergAI(score_fn=_device_fn())) == run(SynergAI())
+    return jobs, dict(fleet=fleet, seed=7, serving=serving)
+
+
+@pytest.mark.parametrize("case", ["job-None-False", "batched-None-False",
+                                  "batched-streaming2-False",
+                                  "batched-streaming3-True", "paper-DH-FH",
+                                  "fleet-disagg-streaming"])
+def test_device_drop_in_matches_numpy(configdict, case):
+    """Byte-identical schedules, job for job: the four mixes on the
+    5-pool fleet, the paper's DH-FH experiment on its 3-pool testbed,
+    and a disaggregated batched streaming MMPP on 8 pools."""
+    cd = configdict
+    jobs, kw = _drop_in_case(cd, case)
+    run = lambda pol: _result_key(Simulator(cd, pol, **kw).run(jobs))
+    assert run(SynergAI(device=True)) == run(SynergAI())
 
 
 @pytest.mark.parametrize("serving", ["job", "batched"])
@@ -257,7 +271,7 @@ def test_device_drop_in_under_failures_elastic_energy(configdict,
                                       mttr_s=span / 6, seed=5),
               elastic_max=3, elastic_threshold=4)
     run = lambda pol: _result_key(Simulator(cd, pol, **kw).run(jobs))
-    assert run(SynergAI(score_fn=_device_fn(), energy_weight=0.5)) == \
+    assert run(SynergAI(device=True, energy_weight=0.5)) == \
         run(SynergAI(energy_weight=0.5))
 
 
@@ -266,7 +280,7 @@ def test_pr2_golden_reproduced_device(configdict):
     jobs = scenario(configdict, "mmpp", n_jobs=40, fleet=fleet, seed=7,
                     utilization=1.2, serving="batched")
     res = {r.job.id: r for r in
-           Simulator(configdict, SynergAI(score_fn=_device_fn()),
+           Simulator(configdict, SynergAI(device=True),
                      fleet=fleet, seed=7, serving="batched").run(jobs)}
     for jid, worker, start, end, exec_s, violated in PR2_GOLDEN:
         r = res[jid]
@@ -282,7 +296,7 @@ def test_stream_golden_reproduced_device(configdict):
     jobs = scenario(configdict, "poisson", n_jobs=12, fleet=fleet,
                     seed=11, utilization=1.0, serving="batched")
     res = {r.job.id: r for r in
-           Simulator(configdict, SynergAI(score_fn=_device_fn()),
+           Simulator(configdict, SynergAI(device=True),
                      fleet=fleet, seed=11, serving="batched").run(jobs)}
     for jid, ttft, tpot in STREAM_GOLDEN:
         assert res[jid].ttft == pytest.approx(ttft, rel=_APPROX), jid
@@ -306,9 +320,9 @@ def test_replay_golden_digest_device_flat_and_hier(configdict,
             for r in sorted(res, key=lambda r: r.job.id))
         return hashlib.sha256(canon.encode()).hexdigest()
 
-    assert digest(SynergAI(score_fn=_device_fn()),
+    assert digest(SynergAI(device=True),
                   synth_fleet(1, 2, 2)) == REPLAY_GOLDEN_DIGEST
-    assert digest(HierarchicalSynergAI(score_fn=_device_fn()),
+    assert digest(HierarchicalSynergAI(device=True),
                   synth_fleet(1, 2, 2, regions=1)) == \
         REPLAY_GOLDEN_DIGEST
 
@@ -321,7 +335,7 @@ def test_hierarchical_region_sliced_device(configdict, regions):
     fleet = synth_fleet(1, 2, 2, regions=regions)
     jobs = scenario(cd, "mmpp", n_jobs=80, fleet=fleet, seed=5,
                     utilization=1.1, serving="batched")
-    pol = HierarchicalSynergAI(score_fn=_device_fn())
+    pol = HierarchicalSynergAI(device=True)
     got = _result_key(Simulator(cd, pol, fleet=fleet, seed=5,
                                 serving="batched").run(jobs))
     want = _result_key(Simulator(cd, HierarchicalSynergAI(),
@@ -344,7 +358,7 @@ def test_steady_tick_transfer_is_o_churn_w(configdict):
     cluster = Simulator(cd, SynergAI(), fleet=fleet).cluster
     jobs = list(scenario(cd, "poisson", n_jobs=512, fleet=fleet,
                          seed=21))
-    pol = SynergAI(score_fn=_device_fn())
+    pol = SynergAI(device=True)
     queue = list(jobs[:480])
     spare = list(jobs[480:])
     pol.schedule(0.0, queue, cluster)    # cold tick: every row uploads
@@ -376,7 +390,7 @@ def test_device_counters_over_full_run(configdict):
     fleet = synth_fleet(1, 2, 2)
     jobs = scenario(cd, "mmpp", n_jobs=120, fleet=fleet, seed=3,
                     utilization=1.2)
-    pol = SynergAI(score_fn=_device_fn())
+    pol = SynergAI(device=True)
     Simulator(cd, pol, fleet=fleet, seed=3).run(jobs)
     dc = pol.cache
     assert dc.flushes == 0

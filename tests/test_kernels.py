@@ -1,6 +1,6 @@
 """Per-kernel validation: sweep shapes/dtypes, assert_allclose against the
 pure-jnp oracle in interpret mode (assignment requirement), plus hypothesis
-property tests on the scheduler-score kernel."""
+property tests on the scheduling-tick kernel."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +13,7 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_routing import moe_routing
 from repro.kernels.rwkv_scan import rwkv_scan
-from repro.kernels.scheduler_score import scheduler_score
+from test_tick_parity import run_tick, tick_inputs, tick_oracle
 
 TOL = dict(rtol=2e-3, atol=2e-3)
 TOL32 = dict(rtol=2e-5, atol=2e-5)
@@ -142,58 +142,6 @@ def test_rwkv_scan_matches_model_layer():
 
 
 # ----------------------------------------------------------------------------
-# scheduler score (the paper's Eq. 2-4 at fleet scale)
-
-
-def test_scheduler_score_matches_oracle():
-    rng = np.random.default_rng(0)
-    J, W = 300, 17
-    qps = rng.uniform(0.5, 100, (J, W)).astype(np.float32)
-    qps[rng.random((J, W)) < 0.2] = 0.0          # infeasible pairs
-    pre = rng.uniform(0.1, 10, (J, W)).astype(np.float32)
-    q = rng.integers(100, 5000, J).astype(np.float32)
-    rem = rng.uniform(1, 2000, J).astype(np.float32)
-    est, best, urg, acc = scheduler_score(qps, pre, q, rem, bj=128,
-                                          interpret=True)
-    est_r, best_r, urg_r, acc_r = ref.scheduler_score_ref(qps, pre, q, rem)
-    feas = qps > 0
-    np.testing.assert_allclose(np.asarray(est)[feas], est_r[feas],
-                               rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(acc), acc_r)
-    np.testing.assert_array_equal(np.asarray(best), best_r)
-    np.testing.assert_allclose(np.asarray(urg), urg_r, rtol=1e-4, atol=1e-2)
-
-
-def test_scheduler_score_matches_core_estimator(configdict):
-    """Kernel vs the production numpy estimator on a real queue."""
-    from repro.core.estimator import estimate_matrix
-    from repro.core.job import make_experiment
-    workers = ["cloud-pod", "edge-large", "edge-small"]
-    jobs = make_experiment(configdict, "DH", "FH", seed=11)
-    now = 100.0
-    s = estimate_matrix(configdict, jobs, workers, now)
-    J, W = len(jobs), len(workers)
-    qps = np.zeros((J, W), np.float32)
-    pre = np.zeros((J, W), np.float32)
-    for ji, job in enumerate(jobs):
-        for wi, w in enumerate(workers):
-            ent = configdict.optimal(job.engine, w)
-            if ent:
-                qps[ji, wi] = ent.qps
-                pre[ji, wi] = ent.preproc_s
-    q = np.array([j.queries for j in jobs], np.float32)
-    rem = np.array([j.t_qos - (now - j.arrival) for j in jobs], np.float32)
-    est, best, urg, acc = scheduler_score(qps, pre, q, rem, interpret=True)
-    feas = np.isfinite(s.t_estimated)
-    np.testing.assert_allclose(np.asarray(est)[feas],
-                               s.t_estimated[feas].astype(np.float32),
-                               rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(acc).astype(bool), s.acceptable)
-    np.testing.assert_allclose(np.asarray(urg), s.urgency.astype(np.float32),
-                               rtol=1e-4, atol=0.5)
-
-
-# ----------------------------------------------------------------------------
 # pure-*numpy* oracles (independent of the jnp ref module) + the padding
 # edges, so moe_routing / rwkv_scan / the ops wrappers stop being dark
 
@@ -302,100 +250,84 @@ def test_ops_wrappers_match_references():
         np.asarray(ops.rwkv_scan(r_, k_, v_, w_, u_, chunk=32)),
         _rwkv_np(r_, k_, v_, w_, u_), rtol=2e-4, atol=2e-4)
 
-    qps = rng.uniform(0.5, 50, (40, 7)).astype(np.float32)
-    qps[rng.random((40, 7)) < 0.2] = 0.0
-    pre = rng.uniform(0.1, 5, (40, 7)).astype(np.float32)
-    qn = rng.integers(10, 500, 40).astype(np.float32)
-    rem = rng.uniform(1, 500, 40).astype(np.float32)
-    est, best, urg, acc = ops.scheduler_score(qps, pre, qn, rem, bj=64)
-    est_r, best_r, urg_r, acc_r = ref.scheduler_score_ref(qps, pre, qn,
-                                                          rem)
-    np.testing.assert_array_equal(np.asarray(best), best_r)
-    np.testing.assert_array_equal(np.asarray(acc), acc_r)
-    feas = qps > 0
-    np.testing.assert_allclose(np.asarray(est)[feas], est_r[feas],
-                               rtol=1e-5)
-
 
 # ----------------------------------------------------------------------------
-# the fused whole-tick kernel (device-resident path): placement parity
-# against a direct numpy transcription of SynergAI._place
+# the scheduling-tick kernel (the paper's Eq. 2-4 at fleet scale):
+# placement parity against the numpy transcription of SynergAI._place
 
 
-def test_scheduler_tick_matches_numpy_placement():
-    import jax.numpy as jnp2
-    from repro.kernels.scheduler_score import scheduler_tick
+def _random_tick(J, W, seed, infeasible, rem_hi):
+    """Random pool rows (``infeasible`` share inf), budgets from -5 to
+    ``rem_hi`` (straddling zero), live depth penalties, busy waits and a
+    partly open fleet."""
+    rng = np.random.default_rng(seed)
+    t = rng.uniform(0.5, 30, (J, W))
+    t[rng.random((J, W)) < infeasible] = np.inf
+    return tick_inputs(
+        t, rng.uniform(-5, rem_hi, J),
+        pen=np.where(rng.random(W) < 0.3, 2.0, 1.0),
+        bw=rng.uniform(0, 10, W), avail=rng.random(W) < 0.6)
 
-    rng = np.random.default_rng(3)
-    cap, W, J, bj = 64, 16, 40, 8
-    Jp = 40
-    pool = rng.uniform(0.5, 30, (cap, W)).astype(np.float32)
-    pool[rng.random((cap, W)) < 0.15] = np.inf
-    slots = rng.permutation(cap)[:J].astype(np.int32)
-    t_rem = rng.uniform(-5, 40, J).astype(np.float32)
-    pen = np.where(rng.random(W) < 0.3, 2.0, 1.0).astype(np.float32)
-    bw = rng.uniform(0, 10, W).astype(np.float32)
-    avail = rng.random(W) < 0.6
-    zero = np.zeros(J, np.int32)
-    inf = np.full(J, np.inf, np.float32)
-    one = np.ones(J, np.float32)
-    emask = np.ones((1, W), bool)
-    assign, order = scheduler_tick(
-        jnp2.asarray(pool), jnp2.asarray(pool), jnp2.asarray(pool),
-        jnp2.zeros((1, W), jnp2.float32), jnp2.asarray(slots),
-        jnp2.asarray(t_rem), jnp2.asarray(inf), jnp2.asarray(inf),
-        jnp2.asarray(one), jnp2.asarray(zero), jnp2.asarray(zero),
-        jnp2.asarray(zero), jnp2.asarray(zero), jnp2.asarray(emask),
-        jnp2.asarray(pen), jnp2.asarray(bw),
-        jnp2.asarray(np.zeros(W, np.float32)), jnp2.asarray(avail),
-        bj=bj, interpret=True)
-    assign, order = np.asarray(assign), np.asarray(order)
 
-    # numpy transcription of the scoring + _place walk
-    t = pool[slots] * pen[None, :]
-    acc = t_rem[:, None] >= t
-    urg = t_rem - pool[slots].min(axis=1)
-    doom = ~acc.any(axis=1)
-    feas = np.isfinite(t)
-    costd = t + bw[None, :]
-    best = np.where(feas, costd, np.inf).min(axis=1)
-    elig = np.where(doom[:, None], feas & (t <= 1.5 * best[:, None]),
-                    acc)
-    ranked = np.where(elig, np.where(doom[:, None], costd, t), np.inf)
-    want_order = np.lexsort((urg, doom))
-    np.testing.assert_array_equal(order, want_order)
-    want = np.full(J, -1, np.int32)
-    open_slots = avail.copy()
-    for ji in want_order:
-        cand = np.where(open_slots, ranked[ji], np.inf)
-        wi = int(cand.argmin())
-        if np.isfinite(cand[wi]):
-            want[ji] = wi
-            open_slots[wi] = False
-    np.testing.assert_array_equal(assign, want)
+def _paper_tick(cd):
+    """The rows ``estimate_matrix`` gives the DH-FH paper experiment on
+    its 3-pool testbed, every pool open."""
+    from repro.core.estimator import estimate_matrix
+    from repro.core.job import make_experiment
+    jobs = make_experiment(cd, "DH", "FH", seed=11)
+    s = estimate_matrix(cd, jobs, ["cloud-pod", "edge-large",
+                                   "edge-small"], 100.0)
+    return tick_inputs(s.t_estimated, s.t_remaining,
+                       bw=np.array([0.0, 3.0, 7.0]))
+
+
+@pytest.mark.parametrize("case", ["random-40x16-bj8",
+                                  "random-300x17-bj128", "paper-rows"])
+def test_scheduler_tick_matches_numpy_placement(configdict, case):
+    if case == "random-40x16-bj8":
+        inp, bj = _random_tick(40, 16, seed=3, infeasible=0.15,
+                               rem_hi=40), 8
+    elif case == "random-300x17-bj128":
+        # tight budgets: open workers outnumber the jobs they accept, so
+        # doomed jobs place too, by busy wait plus estimate
+        inp, bj = _random_tick(300, 17, seed=0, infeasible=0.2,
+                               rem_hi=2), 128
+    else:
+        inp, bj = _paper_tick(configdict), 8
+    assign, order = run_tick(inp, bj=bj)
+    want = tick_oracle(inp, np.float32)
+    np.testing.assert_array_equal(order, want["order"])
+    np.testing.assert_array_equal(assign, want["assign"])
     assert (assign >= 0).any()          # something actually placed
+    assert want["doom"].any() and not want["doom"].all()
+    if case == "random-300x17-bj128":
+        placed = assign >= 0
+        assert (placed & want["doom"]).any()
+        assert (placed & ~want["doom"]).any()
+
+
+_PROP_J, _PROP_W = 40, 8        # one padded shape: one compile
 
 
 @settings(max_examples=25, deadline=None)
-@given(j=st.integers(1, 40), w=st.integers(1, 8), seed=st.integers(0, 999))
-def test_scheduler_score_property(j, w, seed):
+@given(j=st.integers(1, _PROP_J), w=st.integers(1, _PROP_W),
+       seed=st.integers(0, 999))
+def test_scheduler_tick_property(j, w, seed):
     rng = np.random.default_rng(seed)
-    qps = rng.uniform(0, 50, (j, w)).astype(np.float32)
-    pre = rng.uniform(0, 5, (j, w)).astype(np.float32)
-    q = rng.integers(1, 1000, j).astype(np.float32)
-    rem = rng.uniform(-10, 500, j).astype(np.float32)
-    est, best, urg, acc = scheduler_score(qps, pre, q, rem, bj=16,
-                                          interpret=True)
-    est, best, urg, acc = map(np.asarray, (est, best, urg, acc))
-    for ji in range(j):
-        feas = qps[ji] > 0
-        if not feas.any():
-            assert best[ji] == -1
-            continue
-        # Eq. 4: chosen worker is acceptable-minimal when acceptance exists
-        if acc[ji].any():
-            cand = np.where(acc[ji], est[ji], np.inf)
-            assert np.isclose(est[ji][best[ji]], cand.min())
-        # urgency consistent with the min estimate
-        assert np.isclose(urg[ji], rem[ji] - est[ji][feas].min(),
-                          rtol=1e-4, atol=1e-2)
+    t = rng.uniform(0.1, 50, (j, w))
+    t[rng.random((j, w)) < 0.2] = np.inf
+    inp = tick_inputs(t, rng.uniform(-10, 60, j),
+                      pen=np.where(rng.random(w) < 0.3, 1.5, 1.0),
+                      bw=rng.uniform(0, 10, w), avail=rng.random(w) < 0.5)
+    assign, order = run_tick(inp, bj=8, Jp=_PROP_J, Wp=_PROP_W)
+    want = tick_oracle(inp, np.float32)
+    placed = assign[assign >= 0]
+    # each worker takes at most one job, and only an open one
+    assert len(set(placed)) == len(placed)
+    assert inp["avail"][placed].all()
+    # an assigned job was eligible on its worker
+    ji = np.nonzero(assign >= 0)[0]
+    assert want["elig"][ji, assign[ji]].all()
+    # the order is the stable (doomed, urgency) sort
+    np.testing.assert_array_equal(order,
+                                  np.lexsort((want["urg"], want["doom"])))

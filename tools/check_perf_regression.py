@@ -28,8 +28,9 @@ so the ratio is hardware-independent.  The drift ratio is not even a
 timing: fixed seeds and a fixed degradation timeline make the
 violation counts deterministic, so any drift at all is a code change.
 
-``pallas-resident`` variant keys (and their ``-compiled`` twins) are
-**parity-gated, ratio-tracked**: an ``assignments_match_cached: false``
+``pallas-resident`` variant keys (the device-resident tick,
+``SynergAI(device=True)``, and their ``-compiled`` twins from a TPU run)
+are **parity-gated, ratio-tracked**: an ``assignments_match_cached: false``
 in the fresh run fails the gate unconditionally, while their speed
 ratios are printed (``trk``) but never floored — interpret-mode
 wall-clock is an emulation artifact, and no compiled accelerator
@@ -89,7 +90,7 @@ def main(argv=None):
                   f"numpy path")
             failures.append(
                 f"{key}: assignments_match_cached is False — the "
-                f"Pallas path lost bit-for-bit parity")
+                f"device path lost bit-for-bit parity")
         # pallas-resident speed ratios are *tracked*, never floored:
         # interpret-mode wall-clock is an emulation artifact, and no
         # compiled accelerator baseline is committed yet
