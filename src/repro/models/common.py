@@ -1,9 +1,12 @@
 """Shared model primitives: norms, RoPE, attention (all variants), MLPs.
 
-All attention here is the pure-JAX (XLA) path used for the multi-device
-dry-run and CPU smoke tests.  The TPU hot-path Pallas kernels in
-``repro.kernels`` implement the same math (validated against ``kernels.ref``)
-and are swapped in on real hardware via ``cfg.use_pallas`` at the ops layer.
+All attention here is the pure-JAX (XLA) path, the one every model serves
+and the multi-device dry-run compiles: ``attention`` sends one query
+position per row (decode) to ``decode_attention``, which reads the cache
+where the decode loop keeps it, long sequences to
+``chunked_flash_attention`` and short ones to ``naive_attention``.  The
+Pallas kernels in ``repro.kernels`` implement the same math (validated
+against ``kernels.ref``); no model calls them.
 """
 
 from __future__ import annotations
@@ -11,12 +14,30 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import get_active_mesh
+
+
+class StackedLayer(NamedTuple):
+    """Layer ``i`` of an array stacked over a scan group's layers (a
+    cache leaf, [n, B, ...], or held experts, [n, E, ...]): what the
+    decoder's loops hand a sublayer in place of the layer's own array."""
+    stack: jax.Array
+    i: jax.Array
+
+    @property
+    def shape(self):
+        return self.stack.shape[1:]
+
+    def read(self):
+        return jax.lax.dynamic_index_in_dim(self.stack, self.i, 0,
+                                            keepdims=False)
+
 
 # ----------------------------------------------------------------------------
 # initializers
@@ -242,10 +263,83 @@ def chunked_flash_attention(q, k, v, *, causal=True, window=None,
     return out
 
 
+def _positions(x, start, size):
+    """Positions [start, start + size) along axis 1 of ``x``: a [B, S, ...]
+    array, or the layer a ``StackedLayer`` views, sliced from its stack
+    so that no copy of the layer is made."""
+    if isinstance(x, StackedLayer):
+        zero = jnp.zeros((), jnp.int32)
+        at = (x.i, zero, start) + (zero,) * (x.stack.ndim - 3)
+        sizes = (1, x.stack.shape[1], size) + x.stack.shape[3:]
+        return jax.lax.dynamic_slice(x.stack, at, sizes)[0]
+    return jax.lax.dynamic_slice_in_dim(x, start, size, axis=1)
+
+
+def decode_attention(q, k, v, *, chunk, k_valid=None, scale=None):
+    """Attention of one query position per row over a cache, the decode
+    path.  q: [B, 1, H, hd]; k, v: [B, S, K, hd] and [B, S, K, hd_v] (GQA
+    K | H), each an array or a ``StackedLayer`` view of the layer in the
+    decode loop's stacked cache.
+
+    Each kv head's G = H / K query heads are its query rows, so no K or V
+    position is repeated to the H heads.  An online softmax walks the
+    cache ``chunk`` positions at a time, slicing each chunk from the cache
+    where it lies; it reads the cache in one pass where ``chunk`` does
+    not divide S, and on a mesh, whose devices each hold a share of the
+    positions.  Cache operands enter both products in the dtype they are
+    stored in; the products accumulate in f32 and the probabilities enter
+    theirs as f32.  ``k_valid``: number of valid cache positions;
+    ``scale``: the softmax scale (default 1/sqrt(hd)).
+    """
+    B, _, H, hd = q.shape
+    S, K = k.shape[1:3]
+    hd_v = v.shape[-1]
+    G = H // K
+    qg = q.reshape(B, K, G, hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
+    if S % chunk or get_active_mesh() is not None:
+        # a mesh shards the positions, so each device reads its share in
+        # one pass; a chunk sliced there would be gathered from them all
+        chunk = S
+    # bf16 operands multiply exactly either way; HIGHEST keeps an f32
+    # operand (the probabilities, an f32 cache) from being rounded to
+    # bf16 on the TPU
+    highest = jax.lax.Precision.HIGHEST
+
+    def step(j, carry):
+        m, l, acc = carry
+        start = j * chunk
+        s = jnp.einsum("bkgd,bskd->bkgs", qg, _positions(k, start, chunk),
+                       precision=highest,
+                       preferred_element_type=jnp.float32) * scale
+        if k_valid is not None:
+            s = jnp.where(start + jnp.arange(chunk) < k_valid, s, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bkgs,bskd->bkgd", p, _positions(v, start, chunk),
+            precision=highest, preferred_element_type=jnp.float32)
+        return m_new, l * corr + p.sum(axis=-1), acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, S // chunk, step,
+        (jnp.full((B, K, G), -jnp.inf, jnp.float32),
+         jnp.zeros((B, K, G), jnp.float32),
+         jnp.zeros((B, K, G, hd_v), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.reshape(B, 1, H, hd_v).astype(q.dtype)
+
+
 def attention(cfg: ModelConfig, q, k, v, *, causal=True, window=None,
               q_offset=0, k_valid=None, scale=None):
-    """Dispatch: chunked flash for long sequences, naive for short ones.
+    """Dispatch: ``decode_attention`` for one query position attending
+    without a mask of positions (k and v may then be ``StackedLayer``
+    views); else chunked flash for long sequences, naive for short ones.
     ``scale`` is the softmax scale, 1/sqrt(head dim) by default."""
+    if q.shape[1] == 1 and not causal and window is None:
+        return decode_attention(q, k, v, chunk=cfg.attn_chunk,
+                                k_valid=k_valid, scale=scale)
     if k.shape[1] >= cfg.flash_threshold:
         return chunked_flash_attention(q, k, v, causal=causal, window=window,
                                        chunk=cfg.attn_chunk, q_offset=q_offset,

@@ -15,15 +15,16 @@ Uniform sublayer interface so the generic decoder can scan stacked layers:
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import common
-from repro.models.common import (apply_rope, attention, dense_init,
-                                 head_rms_norm, rms_norm, rope_freqs)
+from repro.models.common import (StackedLayer, apply_rope, attention,
+                                 dense_init, head_rms_norm, rms_norm,
+                                 rope_freqs)
 
 # When True, decode-cache writes use a one-hot masked update instead of
 # dynamic_update_slice.  DUS at a dynamic index on a sequence-sharded cache
@@ -39,18 +40,6 @@ ONEHOT_CACHE_UPDATE = False
 SHARDED_DECODE_ATTN = False
 
 
-class StackedLayer(NamedTuple):
-    """Layer ``i`` of an array stacked over a scan group's layers (a
-    cache leaf, [n, B, ...], or held experts, [n, E, ...]): what the
-    decoder's loops hand a sublayer in place of the layer's own array."""
-    stack: jax.Array
-    i: jax.Array
-
-    def read(self):
-        return jax.lax.dynamic_index_in_dim(self.stack, self.i, 0,
-                                            keepdims=False)
-
-
 def read_layer(cache):
     """``cache`` with each ``StackedLayer`` view replaced by its layer."""
     return jax.tree.map(StackedLayer.read, cache,
@@ -62,9 +51,8 @@ def _cache_write(buf: StackedLayer, update, idx):
     layer ``buf`` views.
 
     The one position goes into the stack the decode loop carries, at
-    (i, :, idx), and attention reads layer ``i`` of the written stack, so
-    no layer of the cache is copied.  Returns (the written view, its
-    [B, S, ...] layer).
+    (i, :, idx), so no layer of the cache is copied.  Returns the written
+    view, which decode attention reads where it lies.
     """
     if ONEHOT_CACHE_UPDATE:
         layer = buf.read()
@@ -78,8 +66,7 @@ def _cache_write(buf: StackedLayer, update, idx):
         zero = jnp.zeros((), jnp.int32)
         start = (buf.i, zero, idx) + (zero,) * (update.ndim - 2)
         stack = jax.lax.dynamic_update_slice(buf.stack, update[None], start)
-    out = StackedLayer(stack, buf.i)
-    return out, out.read()
+    return StackedLayer(stack, buf.i)
 
 
 def init_moe_load():
@@ -159,25 +146,26 @@ def attn_sublayer(p, cfg: ModelConfig, x, *, mode, cache, pos, window):
             idx = jax.lax.rem(pos, jnp.int32(window))
         else:
             idx = pos
-        new_k, ck = _cache_write(cache["k"], k, idx)
-        new_v, cv = _cache_write(cache["v"], v, idx)
+        new_k = _cache_write(cache["k"], k, idx)
+        new_v = _cache_write(cache["v"], v, idx)
         new_cache = {"k": new_k, "v": new_v}
-        buf = ck.shape[1]
+        buf = new_k.shape[1]
         if window is not None:
             # every slot in the ring is within the window once warm; a
             # validity bound covers the cold start.
             k_valid = jnp.minimum(pos + 1, buf)
-            out = attention(cfg, q, ck, cv, causal=False, window=None,
+            out = attention(cfg, q, new_k, new_v, causal=False, window=None,
                             k_valid=k_valid)
         else:
             out = None
             if SHARDED_DECODE_ATTN:
                 from repro.distributed.sharding import \
                     sharded_decode_attention
-                out = sharded_decode_attention(q, ck, cv, pos + 1)
+                out = sharded_decode_attention(q, new_k.read(),
+                                               new_v.read(), pos + 1)
             if out is None:
-                out = attention(cfg, q, ck, cv, causal=False, window=None,
-                                k_valid=pos + 1)
+                out = attention(cfg, q, new_k, new_v, causal=False,
+                                window=None, k_valid=pos + 1)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 
@@ -202,7 +190,7 @@ def cross_sublayer(p, cfg: ModelConfig, x, *, mode, cache, ctx):
     if cfg.qk_norm:
         q = head_rms_norm(q, p["q_norm"], cfg.norm_eps)
     if mode == "decode" and cache is not None:
-        ck, cv = read_layer(cache["ck"]), read_layer(cache["cv"])
+        ck, cv = cache["ck"], cache["cv"]   # views, read where they lie
         new_cache = cache  # read only: handed back as it came
     else:
         ck = jnp.einsum("bsd,dhk->bshk", ctx, p["wk"])
@@ -298,24 +286,26 @@ def mla_sublayer(p, cfg: ModelConfig, x, *, mode, cache, pos):
     scale = mla_softmax_scale(cfg)
 
     if mode == "decode":
-        new_ckv, ckv_full = _cache_write(cache["ckv"], ckv, pos)
-        new_krope, krope_full = _cache_write(cache["krope"], k_rope, pos)
+        new_ckv = _cache_write(cache["ckv"], ckv, pos)
+        new_krope = _cache_write(cache["krope"], k_rope, pos)
         new_cache = {"ckv": new_ckv, "krope": new_krope}
         with jax.named_scope("mla.project"):
             q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"])
             q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)
         with jax.named_scope("mla.latent_attend"):
-            # one latent head shared by every query head (MQA)
-            k_cat = jnp.concatenate([ckv_full, krope_full],
+            # one latent head shared by every query head (MQA); the
+            # values are the latent itself, read where the stack holds it
+            ckv_full = new_ckv.read()
+            k_cat = jnp.concatenate([ckv_full, new_krope.read()],
                                     axis=-1)[:, :, None, :]
-            v_lat = ckv_full[:, :, None, :]
+            v_lat = StackedLayer(new_ckv.stack[..., None, :], new_ckv.i)
             out_lat = None
             if SHARDED_DECODE_ATTN:     # it scales by 1/sqrt(head dim)
                 from repro.distributed.sharding import \
                     sharded_decode_attention
                 out_lat = sharded_decode_attention(
-                    q_cat * (scale * q_cat.shape[-1] ** 0.5), k_cat, v_lat,
-                    pos + 1)
+                    q_cat * (scale * q_cat.shape[-1] ** 0.5), k_cat,
+                    ckv_full[:, :, None, :], pos + 1)
             if out_lat is None:
                 out_lat = attention(cfg, q_cat, k_cat, v_lat, causal=False,
                                     k_valid=pos + 1, scale=scale)

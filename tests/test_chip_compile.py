@@ -156,6 +156,87 @@ def test_qwen3_4b_decode_writes_cache_in_place(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+def _materialized(hlo: str) -> dict:
+    """Instruction name -> (dtype, dims) for every array-valued
+    instruction outside a fused computation: the arrays the program
+    writes to memory, not the values a fusion keeps to itself."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo))
+    out, comp = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]", line)
+        if m and comp not in fused:
+            out[m.group(1)] = (m.group(2), tuple(
+                int(d) for d in m.group(3).split(",") if d))
+    return out
+
+
+def _attention_products(hlo: str) -> list:
+    """(lhs dtype, rhs dtype, operand precision) of each product that
+    decode attention's scores or values make, from the optimized HLO."""
+    dtypes = dict(re.findall(r"%([\w.\-]+) = (\w+)\[", hlo))
+    out = []
+    for line in hlo.splitlines():
+        m = re.search(r" (?:convolution|dot)\(%([\w.\-]+), %([\w.\-]+)\)",
+                      line)
+        if m and re.search(r"bkg[ds],bskd->bkg[sd]/dot_general", line):
+            prec = re.search(r"operand_precision=\{([^}]*)\}", line)
+            out.append((dtypes[m.group(1)], dtypes[m.group(2)],
+                        prec.group(1) if prec else "default,default"))
+    return out
+
+
+def test_qwen3_4b_decode_attention_reads_the_cache_in_place(
+        one_chip, no_compile_cache):
+    """The decode step at the ``qwen3-4b.decode`` cell's shape (4 rows x
+    2,560 positions) reads K and V where the stacked cache holds them:
+    no array outside a fusion holds one layer's K or V, no array
+    anywhere holds a chunk widened to the 32 query heads, and no array
+    holds a cache chunk in f32.  Both products multiply bf16 operands,
+    which the MXU takes whole, or run at HIGHEST precision: neither
+    rounds an f32 operand to bf16."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs.registry import get_config
+    from repro.models.registry import build_model
+
+    cfg = get_config("qwen3-4b")
+    B, L, K, hd, C = 4, 2560, cfg.n_kv_heads, cfg.head_dim, cfg.attn_chunk
+    G = cfg.n_heads // K
+    model = build_model(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    params = place(jax.eval_shape(model.init_params,
+                                  jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(lambda: model.init_cache(B, L)))
+    step = place({"token": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                  "pos": jax.ShapeDtypeStruct((), jnp.int32)})
+    hlo = jax.jit(model.decode, donate_argnums=(1,)).lower(
+        params, caches, step).compile().as_text()
+    arrays = _materialized(hlo)
+    shapes = _hlo_shapes(hlo)
+    assert (cfg.n_layers, B, L, K, hd) in shapes.values()
+
+    layer = {(1, B, L, K, hd), (B, L, K, hd)}
+    assert [n for n, (_, s) in arrays.items() if s in layer] == []
+    widened = {(B, C, K, G, hd), (B, C, K * G, hd)}
+    assert [n for n, s in shapes.items() if s in widened] == []
+    chunk = {(B, C, K, hd), (1, B, C, K, hd)}
+    f32_chunks = re.findall(
+        r"%([\w.\-]+) = f32\[([\d,]*)\]", hlo)
+    assert [n for n, d in f32_chunks
+            if tuple(int(x) for x in d.split(",") if x) in chunk] == []
+    products = _attention_products(hlo)
+    assert len(products) == 2
+    assert [p for p in products if p[:2] != ("bf16", "bf16")
+            and p[2] != "highest,highest"] == []
+
+
 @pytest.fixture(scope="module")
 def deepseek_share(one_chip):
     """The model and parameter shapes of one chip's share of DeepSeek-V2
@@ -275,13 +356,15 @@ def test_deepseek_v2_programs_read_the_held_experts_in_place(
     assert temp <= DEEPSEEK_DECODE_TEMP_WITH_COPIES
 
 
-# temp bytes of qwen3-4b's programs at the benchmark cells' shapes, as
-# compiled before the MLA and expert layers changed the shared attention
-# and decoder code; the qwen3-4b programs must not move
+# temp bytes of qwen3-4b's programs at the benchmark cells' shapes: the
+# prefill programs as compiled before the MLA and expert layers changed
+# the shared attention and decoder code, the decode programs since
+# decode attention reads the cache where it lies (581,120 and 354,816
+# before); a change to shared code must not move them unnoticed
 QWEN3_4B_TEMP = {("prefill", 4, 2048): 1_024_669_184,
                  ("prefill", 1, 4096): 315_315_712,
-                 ("decode", 4, 2560): 581_120,
-                 ("decode", 1, 4224): 354_816}
+                 ("decode", 4, 2560): 483_840,
+                 ("decode", 1, 4224): 387_072}
 
 
 @pytest.mark.parametrize("kind,B,L", sorted(QWEN3_4B_TEMP),

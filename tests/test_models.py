@@ -163,3 +163,49 @@ def test_flash_attention_matches_naive():
                                              window=window, chunk=32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+
+# decode position per case; k_valid is min(pos + 1, buffer), as a ring
+# buffer's, and the cache is read in chunks of 16
+_DECODE_POS = {"one": 0, "mid-chunk": 22, "chunk-boundary": 31,
+               "whole": 100}
+
+
+@pytest.mark.parametrize("kv_case", sorted(_DECODE_POS))
+@pytest.mark.parametrize("layout", ["gqa", "latent", "ring", "bf16-cache"])
+def test_decode_attention_matches_naive(layout, kv_case):
+    """One query position per row, not causal: ``attention`` takes the
+    decode path, which reads layer 1 of 3 stacked caches through
+    ``StackedLayer`` views, and agrees with ``naive_attention`` on that
+    layer: GQA (8 query heads over 2 kv heads, 64 positions); one latent
+    head whose keys (24 wide) are wider than its values (16), at an
+    explicit scale; a ring buffer of 40 positions, which the chunk does
+    not divide, read in one pass; a bf16 cache under f32 queries."""
+    import dataclasses
+
+    from repro.models import common
+
+    cfg = dataclasses.replace(reduced(get_config("qwen3-4b")),
+                              attn_chunk=16)
+    B, n, H, K, S, hd, hd_v, scale = 2, 3, 8, 2, 64, 16, 16, None
+    if layout == "latent":
+        K, hd, scale = 1, 24, 0.37
+    if layout == "ring":
+        S = 40
+    kv = min(_DECODE_POS[kv_case] + 1, S)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, 1, H, hd))
+    k = jax.random.normal(ks[1], (n, B, S, K, hd))
+    v = jax.random.normal(ks[2], (n, B, S, K, hd_v))
+    if layout == "bf16-cache":
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    i = jnp.int32(1)
+    out = jax.jit(lambda q, k, v, kv: common.attention(
+        cfg, q, common.StackedLayer(k, i), common.StackedLayer(v, i),
+        causal=False, k_valid=kv, scale=scale))(q, k, v, jnp.int32(kv))
+    ref = common.naive_attention(q, k[1], v[1], causal=False, k_valid=kv,
+                                 scale=scale)
+    assert out.shape == (B, 1, H, hd_v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
